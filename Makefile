@@ -1,6 +1,5 @@
 # Convenience targets; CI runs the same commands (.github/workflows/ci.yml).
-# `test` uses whatever python is active — tests degrade gracefully when
-# `hypothesis` is absent (tests/_hypothesis_compat.py).
+# `test` uses whatever python is active (jax, pytest and hypothesis).
 
 PY ?= python
 MDFLAGS = XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu

@@ -80,6 +80,7 @@ import numpy as np
 
 from repro.analysis.jit_cache import assert_zero_retrace
 from repro.analysis.opcount import activation_moves, count_dynamic_ops
+from repro.launch.mesh import make_mesh
 from repro.runtime import dispatch as D
 
 OUT = os.path.join(os.path.dirname(__file__), "out")
@@ -164,7 +165,7 @@ def _autotune_leg(rows, *, quick, devices, drop_budget=0.05):
 
     ladder = (OperatingPoint(0.5, 0.15), OperatingPoint(0.5, 0.3),
               OperatingPoint(0.6, 0.5), OperatingPoint(1.0, 1.0))
-    mesh = jax.make_mesh((devices,), ("data",)) if devices > 1 else None
+    mesh = make_mesh((devices,), ("data",)) if devices > 1 else None
     tl = t // devices
     ctrl = CapacityController(
         ladder, lambda pt: point_caps(pt, tl, n, n_shards=devices),
@@ -298,7 +299,7 @@ def _qos_leg(rows, *, quick, devices=1):
               OperatingPoint(1.0, 1.0)]
     mixes = [(1, 1, 1), (3, 1, 1), (1, 1, 3)]    # balanced / tight- / loose-heavy
 
-    mesh = jax.make_mesh((devices,), ("data",)) if devices > 1 else None
+    mesh = make_mesh((devices,), ("data",)) if devices > 1 else None
     tl = t // devices
     fns = {}                                  # (rung, backend) -> jitted fn
 
@@ -412,7 +413,7 @@ def _library_leg(rows, *, quick, devices=1):
                                         ep[1])
     W = ops.prepad_switched_weights(w1, b1, w2, b2)   # full library, once
 
-    mesh = jax.make_mesh((devices,), ("data",)) if devices > 1 else None
+    mesh = make_mesh((devices,), ("data",)) if devices > 1 else None
     tl = t // devices
     ec = shard_capacity(tl, 0.5)
     ic = shard_capacity(tl, 0.3)                      # per resident slot
@@ -783,7 +784,7 @@ def _backend_sweep_sharded(rows, *, quick, iters, devices):
         key, t, n, d, d_h, d_ff)
     exact_fn_p = lambda ep, xb: jnp.dot(jax.nn.silu(jnp.dot(xb, ep[0])),
                                         ep[1])
-    mesh = jax.make_mesh((devices,), ("data",))
+    mesh = make_mesh((devices,), ("data",))
     outs = {}
     for backend in D.DISPATCH_BACKENDS:
         interp = on_cpu and backend in D.PALLAS_BACKENDS
@@ -858,7 +859,7 @@ def main(quick: bool = False, iters: int | None = None, devices: int = 1,
             assert t % devices == 0, (t, devices)
             tl = t // devices
             ec_l, ic_l = max(tl // 2, 1), max(int(tl * 0.4), 1)
-            mesh = jax.make_mesh((devices,), ("data",))
+            mesh = make_mesh((devices,), ("data",))
             exact_fn_p = lambda ep, xb: jnp.dot(
                 jax.nn.silu(jnp.dot(xb, ep[0])), ep[1])
             outs_sh = {}

@@ -40,15 +40,18 @@ def fused_vmem(t, d_in, d_h, d_out, block_t, itemsize=2):
     """Fused dispatch kernel residency: the whole (T, d_in) activation
     block and the (T+1, d_out_p) output block live in VMEM for the full
     grid (that's what makes the in-kernel gather/scatter free of HBM
-    traffic), plus one weight tile and the (block_t, d_in_p) gather
-    scratch.  This is the bound that decides when fusion is sound —
-    past it, fall back to the unfused class-sort path."""
+    traffic), plus one weight tile and the compiled branch's f32 staging
+    scratch: the activation and output blocks again, and one tile of
+    each.  This is the bound that decides when fusion is sound — past
+    it, fall back to the unfused class-sort path."""
     pad = lambda v: ((v + LANE - 1) // LANE) * LANE
+    pad8 = lambda v: ((v + 7) // 8) * 8
     d_in_p, d_h_p, d_out_p = pad(d_in), pad(d_h), pad(d_out)
     resident = t * d_in + (t + 1) * d_out_p
     tile = d_in_p * d_h_p + d_h_p + d_h_p * d_out_p + d_out_p
-    scratch = block_t * d_in_p
-    return (resident + tile + scratch) * itemsize
+    scratch = (pad8(t) * d_in_p + block_t * (d_in_p + d_out_p)
+               + pad8(t + 1) * d_out_p)
+    return (resident + tile) * itemsize + scratch * 4
 
 
 def hbm_saving(t, d_h, itemsize=2):

@@ -17,10 +17,12 @@ benchmarks/bench_serve.py.
 """
 import argparse
 import dataclasses
+import functools
 
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.registry import get_config, smoke_config
 from repro.models import model as M
 from repro.runtime.cli import add_serve_options
@@ -37,6 +39,7 @@ def main(argv=None):
     add_serve_options(ap, batch=4, max_len=96)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = smoke_config(get_config(args.arch))
     options = ServeOptions.from_args(args)
     if args.approx or options.use_mcma_dispatch:
@@ -45,7 +48,8 @@ def main(argv=None):
             library_size=options.library.library_size
             if options.library else cfg.approx.library_size))
     assert cfg.input_mode == "tokens", "serve demo expects token models"
-    params = M.init_model(jax.random.PRNGKey(0), cfg)
+    params = jax.jit(functools.partial(M.init_model, cfg=cfg))(
+        jax.random.PRNGKey(0))
     server = DecodeServer(cfg, params, options=options)
 
     rng = np.random.default_rng(0)

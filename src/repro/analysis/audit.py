@@ -30,6 +30,7 @@ jitted function — tests use them directly instead of copy-pasting
 from __future__ import annotations
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 
 from repro.analysis.findings import Finding
@@ -86,9 +87,9 @@ def stats_dtype_findings(stats, *, scope: str,
 
 
 def _sub_jaxprs(value):
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, jex_core.ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, jex_core.Jaxpr):
         yield value
     elif isinstance(value, (list, tuple)):
         for v in value:

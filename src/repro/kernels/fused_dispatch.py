@@ -27,8 +27,9 @@ pseudo-class exactly as in the unfused kernel and come out exactly zero.
 
 When fusion is sound: the whole activation block (T, d_in) and the
 (T + 1, d_out) output must fit VMEM simultaneously with one weight block
-— decode-tick batches do comfortably (a 1024×512 f32 block is 2 MiB
-against ~16 MiB VMEM on v5e); past that, fall back to the unfused
+(the compiled branch also holds an f32 copy of each) — decode-tick
+batches do comfortably (a 1024×512 f32 block is 2 MiB against ~16 MiB
+VMEM on v5e); past that, fall back to the unfused
 ``backend="pallas"`` path whose tiles stream.  The kernel keeps two
 I/O strategies behind the static ``vector_io`` flag:
 
@@ -37,12 +38,16 @@ I/O strategies behind the static ``vector_io`` flag:
     these lower to plain XLA gathers on the VMEM-resident block values —
     the CI-measurable form — and XLA keeps the revisited full-array
     blocks in place across grid steps.
-  * ``vector_io=False`` (default compiled): per-row dynamic-slice copies
-    (``fori_loop`` over SMEM row ids) — the Mosaic-friendly DMA form for
-    real TPU runs.  Both branches are bit-identical (pinned in
-    tests/test_fused_dispatch.py); the compute between them is shared
-    and shape-identical to _switched_kernel, so results match the
-    unfused kernel bit-for-bit.
+  * ``vector_io=False`` (default compiled, the only branch Mosaic
+    lowers): per-row ref-to-ref copies (``fori_loop`` over SMEM row ids,
+    ``pl.ds`` row slices), staged through f32 VMEM scratch because Mosaic
+    moves a single row at a dynamic sublane offset only for 32-bit data.
+    The output is assembled in f32 scratch and written once, cast, at the
+    last grid step.  Both branches are bit-identical (pinned in
+    tests/test_fused_dispatch.py, f32 and bf16); the compute between them
+    is shared and shape-identical to _switched_kernel, so results match
+    the unfused kernel bit-for-bit.  tests/test_tpu_compile.py compiles
+    this branch for a described TPU v5e.
 """
 from __future__ import annotations
 
@@ -52,6 +57,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+def _pad_rows(n: int) -> int:
+    """Round a scratch row count up to whole (8, 128) f32 tiles."""
+    return pl.cdiv(n, 8) * 8
 
 
 def fused_row_index(order: jax.Array, pos: jax.Array, t: int,
@@ -70,7 +80,7 @@ def fused_row_index(order: jax.Array, pos: jax.Array, t: int,
 
 def _fused_kernel(t, block_t, d_in, vector_io,
                   rows_ref, tile_cls_ref, x_ref, w1_ref, b1_ref, w2_ref,
-                  b2_ref, o_ref, xs_ref):
+                  b2_ref, o_ref, *stage):
     del tile_cls_ref  # consumed by the weight index_maps only
     i = pl.program_id(0)
     base = i * block_t
@@ -84,18 +94,26 @@ def _fused_kernel(t, block_t, d_in, vector_io,
         if d_in_p > d_in:
             xs = jnp.pad(xs, ((0, 0), (0, d_in_p - d_in)))
     else:
-        if d_in_p > d_in:
-            @pl.when(i == 0)
-            def _zero_lane_pad():
-                xs_ref[:, d_in:] = jnp.zeros((block_t, d_in_p - d_in),
-                                             xs_ref.dtype)
+        # Mosaic moves single rows at a dynamic sublane offset only for
+        # 32-bit data, so rows are staged through f32 scratch: the whole
+        # activation block once (zero lane padding included), then one
+        # row copy per tile row.  bf16 -> f32 -> bf16 is exact, so the
+        # compute below sees the same bits as the unfused kernel.
+        x32_ref, xs32_ref, ys32_ref, o32_ref = stage
+
+        @pl.when(i == 0)
+        def _stage_activations():
+            if d_in_p > d_in:
+                x32_ref[:, d_in:] = jnp.zeros(
+                    (x32_ref.shape[0], d_in_p - d_in), jnp.float32)
+            x32_ref[:t, :d_in] = x_ref[...].astype(jnp.float32)
 
         def gather_body(k, carry):
             r = jnp.minimum(rows_ref[base + k], t - 1)
-            xs_ref[k, :d_in] = x_ref[r, :]
+            xs32_ref[pl.ds(k, 1), :] = x32_ref[pl.ds(r, 1), :]
             return carry
         jax.lax.fori_loop(0, block_t, gather_body, 0)
-        xs = xs_ref[...]
+        xs = xs32_ref[...].astype(x_ref.dtype)
 
     # ---- compute: identical shapes/ops to _switched_kernel ---------------
     h = jnp.dot(xs, w1_ref[0], preferred_element_type=jnp.float32)
@@ -109,10 +127,16 @@ def _fused_kernel(t, block_t, d_in, vector_io,
     if vector_io:
         o_ref[...] = o_ref[...].at[idx].set(y)
     else:
+        ys32_ref[...] = y.astype(jnp.float32)
+
         def scatter_body(k, carry):
-            o_ref[rows_ref[base + k], :] = y[k, :]
+            o32_ref[pl.ds(rows_ref[base + k], 1), :] = ys32_ref[pl.ds(k, 1), :]
             return carry
         jax.lax.fori_loop(0, block_t, scatter_body, 0)
+
+        @pl.when(i == pl.num_programs(0) - 1)
+        def _flush():
+            o_ref[...] = o32_ref[:t + 1, :].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -166,7 +190,11 @@ def switched_mlp_fused(x: jax.Array, rows: jax.Array, tile_cls: jax.Array,
             pl.BlockSpec((1, 1, d_out_p), _weight),
         ],
         out_specs=pl.BlockSpec((t + 1, d_out_p), _resident),
-        scratch_shapes=[pltpu.VMEM((block_t, d_in_p), x.dtype)],
+        scratch_shapes=[] if vector_io else [
+            pltpu.VMEM((_pad_rows(t), d_in_p), jnp.float32),
+            pltpu.VMEM((block_t, d_in_p), jnp.float32),
+            pltpu.VMEM((block_t, d_out_p), jnp.float32),
+            pltpu.VMEM((_pad_rows(t + 1), d_out_p), jnp.float32)],
     )
     kernel = functools.partial(_fused_kernel, t, block_t, d_in, vector_io)
     return pl.pallas_call(

@@ -3,6 +3,13 @@
     PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \\
         --smoke --requests 16 --max-new 24
 
+Without ``--smoke`` the config is served at its published widths, e.g.
+internlm2-1.8b on one TPU v5e chip:
+
+    PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \\
+        --mcma-dispatch --route-scope tick --kv-page-size 16 --batch 8 \\
+        --max-len 1024
+
 Mesh deployments (``--data``/``--model``) shard params/cache by the
 declarative rules and serve every decode tick through the
 shard_map-native MCMA dispatch when ``--mcma-dispatch`` is on (on 8 CPU
@@ -38,13 +45,17 @@ def main(argv=None):
     add_serve_options(ap, batch=4, max_len=128)
     args = ap.parse_args(argv)
 
+    import functools
+
     import jax
     import numpy as np
+    from repro.compile_cache import use_compile_cache
     from repro.configs.registry import get_config, smoke_config
     from repro.models import model as M
     from repro.runtime.options import ServeOptions
     from repro.runtime.server import DecodeServer, Request
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
@@ -60,7 +71,10 @@ def main(argv=None):
             cfg.approx, enable=True,
             library_size=options.library.library_size
             if options.library else cfg.approx.library_size))
-    params = M.init_model(jax.random.PRNGKey(args.seed), cfg)
+    # one compiled init: a full-width model would otherwise initialise
+    # op by op
+    params = jax.jit(functools.partial(M.init_model, cfg=cfg))(
+        jax.random.PRNGKey(args.seed))
     server = DecodeServer(cfg, params, options=options)
 
     rng = np.random.default_rng(args.seed)

@@ -45,7 +45,8 @@ def main(argv=None):
     mesh = None
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split(","))
-        mesh = jax.make_mesh(shape, ("data", "model")[:len(shape)])
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh(shape, ("data", "model")[:len(shape)])
 
     ds = SyntheticLM(vocab=cfg.vocab, seq_len=args.seq_len,
                      global_batch=args.batch, seed=args.seed)

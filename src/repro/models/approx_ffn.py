@@ -237,7 +237,6 @@ def make_tick_plan(cfg: ModelConfig, params, x: jax.Array,
     mesh, dp, g = _manual_serve_ctx(cfg, b)
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
-        from repro.sharding.compat import shard_map_compat
         from repro.sharding.rules import dispatch_plan_specs
         tl = t // g
         ec, ic = serve_caps(cfg, tl)
@@ -274,7 +273,7 @@ def make_tick_plan(cfg: ModelConfig, params, x: jax.Array,
         if has_res:
             in_specs = in_specs + (P(None),)
             args = args + (residency.astype(jnp.int32),)
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             local, mesh=mesh, in_specs=in_specs,
             out_specs=dispatch_plan_specs(
                 mesh, data_axes=dp,
@@ -282,7 +281,7 @@ def make_tick_plan(cfg: ModelConfig, params, x: jax.Array,
                 exact_cap=ec, invoke_cap=ic, block_t=a.block_t,
                 backend=a.backend, n_tiers=nt,
                 library_size=a.library_size if has_res else 0),
-            axis_names=frozenset(tuple(dp) + ("model",)), check=False)
+            axis_names=frozenset(tuple(dp) + ("model",)), check_vma=False)
         return fn(*args)
 
     xt = x.reshape(t, d)
@@ -419,7 +418,6 @@ def _approx_serve_manual(cfg: ModelConfig, p, x, mesh, dp, row_mask=None,
     """
     from repro.runtime.dispatch import (execute_dispatch, mcma_dispatch,
                                         plan_invoke_stats)
-    from repro.sharding.compat import shard_map_compat
     from repro.sharding.rules import approx_serve_specs
     a = cfg.approx
     b, s, d = x.shape
@@ -464,9 +462,9 @@ def _approx_serve_manual(cfg: ModelConfig, p, x, mesh, dp, row_mask=None,
                 interpret=a.interpret, weights_prepadded=True)
             return out.reshape(bl, sl, d)
 
-        fn = shard_map_compat(local_plan, mesh=mesh, in_specs=specs["in"],
-                              out_specs=specs["out"],
-                              axis_names=frozenset(axes), check=False)
+        fn = jax.shard_map(local_plan, mesh=mesh, in_specs=specs["in"],
+                           out_specs=specs["out"],
+                           axis_names=frozenset(axes), check_vma=False)
         out = fn(weights, x, plan)
         stats = plan_invoke_stats(plan)
     else:
@@ -503,9 +501,9 @@ def _approx_serve_manual(cfg: ModelConfig, p, x, mesh, dp, row_mask=None,
                 tier_margins=tm, residency=res)
             return out.reshape(bl, sl, d), stats
 
-        fn = shard_map_compat(local, mesh=mesh, in_specs=specs["in"],
-                              out_specs=specs["out"],
-                              axis_names=frozenset(axes), check=False)
+        fn = jax.shard_map(local, mesh=mesh, in_specs=specs["in"],
+                           out_specs=specs["out"],
+                           axis_names=frozenset(axes), check_vma=False)
         args = (weights, x, row_mask)
         if has_tier:
             args = args + (tier.astype(jnp.int32), tier_margins)

@@ -91,10 +91,9 @@ def _moe_fwd_manual(cfg: ModelConfig, p, x, mesh, dp, md):
         y = jax.lax.psum(y_part, "model")
         return y, jax.lax.pmean(aux, axes)
 
-    from repro.sharding.compat import shard_map_compat
-    fn = shard_map_compat(local, mesh=mesh, in_specs=specs["in"],
-                          out_specs=specs["out"],
-                          axis_names=frozenset(axes), check=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=specs["in"],
+                       out_specs=specs["out"],
+                       axis_names=frozenset(axes), check_vma=False)
     return fn(p, x)
 
 

@@ -838,7 +838,6 @@ def mcma_dispatch_sharded(mesh, x: jax.Array, logits: jax.Array,
     Returns ``(y, invoke_stats)``: y row-sharded like x, invoke_stats
     psum-reduced to the global totals (replicated on every shard).
     """
-    from repro.sharding.compat import shard_map_compat
     from repro.sharding.rules import dp_axes, mcma_dispatch_specs
     dp = tuple(data_axes) if data_axes is not None else dp_axes(mesh)
     specs = mcma_dispatch_specs(mesh, data_axes=dp,
@@ -860,9 +859,9 @@ def mcma_dispatch_sharded(mesh, x: jax.Array, logits: jax.Array,
             row_mask=m_l, weights_prepadded=weights_prepadded,
             tier=t_l, tier_margins=tm, residency=res)
 
-    fn = shard_map_compat(local, mesh=mesh, in_specs=specs["in"],
-                          out_specs=specs["out"],
-                          axis_names=frozenset(dp), check=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=specs["in"],
+                       out_specs=specs["out"],
+                       axis_names=frozenset(dp), check_vma=False)
     args = (x, logits, exact_params, a_w1, a_b1, a_w2, a_b2)
     if has_mask:
         args = args + (row_mask,)

@@ -96,17 +96,27 @@ def make_prefill_step(cfg: ModelConfig):
 def mcma_serve_config(cfg: ModelConfig, *, backend: str | None = None) -> ModelConfig:
     """Serve-mode cfg routing the ApproxFFN through the MCMA weight-switch
     dispatch engine (runtime/dispatch.py).  Default backend is the Pallas
-    kernel (interpreter mode off-TPU so the same step compiles in CI/CPU
-    runs); ``backend="pallas_fused"`` runs the gather/scatter-fused
+    kernel; ``backend="pallas_fused"`` runs the gather/scatter-fused
     kernel; ``backend="xla"`` swaps in the pure-XLA dispatch — the
-    oracle the benches gate both kernels against."""
+    oracle the benches gate both kernels against.
+
+    The Pallas kernels are TPU kernels: they compile on ``"tpu"`` and run
+    in the Pallas interpreter on ``"cpu"`` (tests, CI).  Any other
+    platform raises rather than silently interpreting."""
     assert cfg.approx.enable, "MCMA dispatch requires cfg.approx.enable"
     backend = backend or "pallas"
     from repro.runtime.dispatch import PALLAS_BACKENDS
+    interpret = False
+    if backend in PALLAS_BACKENDS:
+        platform = jax.default_backend()
+        if platform not in ("tpu", "cpu"):
+            raise RuntimeError(
+                f"dispatch backend {backend!r} runs Pallas TPU kernels: "
+                f"compiled on 'tpu', interpreted on 'cpu'; found "
+                f"platform {platform!r} — serve with backend='xla' there")
+        interpret = platform == "cpu"
     return dataclasses.replace(cfg, approx=dataclasses.replace(
-        cfg.approx, backend=backend,
-        interpret=backend in PALLAS_BACKENDS
-        and jax.default_backend() != "tpu"))
+        cfg.approx, backend=backend, interpret=interpret))
 
 
 @contextlib.contextmanager

@@ -64,11 +64,8 @@ def manual_dp_context():
     spec = _SPEC.get()
     if spec is None or spec[0] is None:
         return None, ()
-    try:
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-    except Exception:
-        return None, ()
+    from jax._src.mesh import thread_resources
+    mesh = thread_resources.env.physical_mesh
     if mesh is None or mesh.empty:
         return None, ()
     dp = spec[0]

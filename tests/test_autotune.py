@@ -305,7 +305,8 @@ _MESH_AUTOTUNE = textwrap.dedent("""
     wo = jax.random.normal(ks[7], (2 * DC, DC)) * 0.1
     exact_fn_p = lambda ep, xb: jnp.dot(jax.nn.silu(jnp.dot(xb, ep[0])),
                                         ep[1])
-    mesh = jax.make_mesh((DEVS,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((DEVS,), ("data",))
 
     ladder = (OperatingPoint(0.5, 0.15), OperatingPoint(0.5, 0.35),
               OperatingPoint(1.0, 1.0))

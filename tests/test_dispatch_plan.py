@@ -241,7 +241,8 @@ _TICK_MESH = textwrap.dedent("""
     _, _, m1 = M.decode(cfg, params, cache, toks, serve=True,
                         collect_metrics=True, row_mask=mask)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     outs, counts = {}, {}
     for backend in ("xla", "pallas"):
         c = cfg_with(backend)
@@ -290,7 +291,8 @@ def test_tick_scope_mesh_inprocess():
     cfg1 = _approx_cfg(route_scope="tick")
     _, _, m1 = M.decode(cfg1, params, cache, toks, serve=True,
                         collect_metrics=True, row_mask=mask)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     outs = {}
     for be in ("xla", "pallas"):
         c = _approx_cfg(backend=be, interpret=True, block_t=16,

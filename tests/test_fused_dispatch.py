@@ -130,12 +130,15 @@ def test_fused_residency_swap_bitexact_and_zero_retrace():
         assert_zero_retrace(f, f"{be}: a residency swap")
 
 
-def test_fused_vector_io_branches_bit_identical():
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_fused_vector_io_branches_bit_identical(dtype):
     """The kernel's two static I/O lowerings (vectorized take/.at vs the
-    per-row fori DMA form) must produce the same bits — the compiled-mode
-    branch is what runs on TPU, the vectorized one in CI."""
+    per-row copies staged through f32 scratch) must produce the same bits
+    — the compiled-mode branch is what runs on TPU, the vectorized one in
+    CI.  bf16 is the served dtype, and the one the f32 staging is for."""
     t, n, d, d_h = 130, 4, 40, 16
     x, _, (w1, b1, w2, b2), _ = _mk_case(jax.random.PRNGKey(3), t, n, d, d_h)
+    x, w1, b1, w2, b2 = (a.astype(dtype) for a in (x, w1, b1, w2, b2))
     cls = jax.random.randint(jax.random.PRNGKey(4), (t,), 0, n)
     ys = [np.asarray(ops.switched_apply_fused(
         x, cls, w1, b1, w2, b2, block_t=32, interpret=True, vector_io=vio))
@@ -254,7 +257,8 @@ _MESH = textwrap.dedent("""
     params = M.init_model(jax.random.PRNGKey(0), cfg_with("xla", "tick"))
     out = {}
     for scope in ("layer", "tick"):
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         outs = {}
         for backend in ("xla", "pallas", "pallas_fused"):
             c = cfg_with(backend, scope)
@@ -298,7 +302,8 @@ def test_fused_sharded_engine_inprocess_8_devices():
         jax.random.PRNGKey(13), t, n, d, d_h)
     wi = jax.random.normal(jax.random.PRNGKey(14), (d, d)) * 0.1
     exact_fn_p = lambda ep, xb: jnp.dot(xb, ep)
-    mesh = jax.make_mesh((8,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("data",))
     outs = {}
     for be in D.DISPATCH_BACKENDS:
         interp = be in D.PALLAS_BACKENDS

@@ -371,7 +371,8 @@ _MESH = textwrap.dedent("""
             _, _, m1 = M.decode(cfg, params, cache, toks, serve=True,
                                 collect_metrics=True, row_mask=mask,
                                 residency=resv)
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
             outs, libs = {}, {}
             for backend in ("xla", "pallas"):
                 c = cfg_with(backend, scope)
@@ -427,7 +428,8 @@ def test_residency_mesh_inprocess(route_scope):
     mask = jnp.asarray([True] * 6 + [False] * 2)
     toks = jnp.arange(1, b + 1, dtype=jnp.int32)[:, None]
     params = M.init_model(jax.random.PRNGKey(0), _lib_cfg())
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     fns = {}
     for be, kw in (("xla", {}),
                    ("pallas", dict(interpret=True, block_t=16))):

@@ -474,7 +474,8 @@ _TIER_MESH = textwrap.dedent("""
         _, _, m1 = M.decode(cfg, params, cache, toks, serve=True,
                             collect_metrics=True, row_mask=mask,
                             tier=tier, tier_margins=margins)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         outs, tcs = {}, {}
         for backend in ("xla", "pallas"):
             c = cfg_with(backend, scope)
@@ -537,7 +538,8 @@ def test_mixed_tier_mesh_inprocess(route_scope):
     _, _, m1 = M.decode(cfg1, params, cache, toks, serve=True,
                         collect_metrics=True, row_mask=mask, tier=tier,
                         tier_margins=MARGINS)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     outs, tcs = {}, {}
     for be in ("xla", "pallas"):
         c = _approx_cfg(backend=be, interpret=True, block_t=16,
@@ -589,7 +591,8 @@ def test_sharded_engine_mixed_tier_psum_equals_single_device():
                                 *w, exact_cap=T // 2, invoke_cap=T // 8,
                                 backend="xla", tier=tier,
                                 tier_margins=margins)
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         _, s8 = D.mcma_dispatch_sharded(
             mesh, x, lg, exact_fn, (wi, wo), *w, exact_cap=T // 16,
             invoke_cap=T // 64, backend="xla", tier=tier,

@@ -34,6 +34,34 @@ def test_server_drains_and_recycles_slots():
     assert stats["ticks"] < 500
 
 
+@pytest.mark.parametrize("platform,backend,interpret", [
+    ("cpu", "pallas", True),
+    ("cpu", "pallas_fused", True),
+    ("tpu", "pallas", False),
+    ("tpu", "pallas_fused", False),
+    ("gpu", "xla", False),
+    ("gpu", "pallas", None),
+    ("rocm", "pallas_fused", None),
+])
+def test_mcma_serve_config_interprets_only_on_cpu(monkeypatch, platform,
+                                                  backend, interpret):
+    """The Pallas kernels compile on TPU and are interpreted on CPU only;
+    on any other platform the serve config raises instead of quietly
+    returning an interpret-mode config (``interpret=None`` marks those)."""
+    from repro.runtime import steps
+    cfg = smoke_config(get_config("internlm2-1.8b"))
+    cfg = dataclasses.replace(cfg, approx=dataclasses.replace(
+        cfg.approx, enable=True))
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match=platform):
+            steps.mcma_serve_config(cfg, backend=backend)
+        return
+    out = steps.mcma_serve_config(cfg, backend=backend)
+    assert out.approx.backend == backend
+    assert out.approx.interpret is interpret
+
+
 def test_server_slot_reset_isolates_requests():
     """A recycled slot must not leak KV state: the same prompt must yield
     the same tokens whether it runs in a fresh server or a recycled slot."""
@@ -97,25 +125,25 @@ _COMPRESSION = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.optim.compression import ef_int8_allreduce_tree, init_error_feedback
 
-    mesh = jax.make_mesh((4,), ("pod",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("pod",))
     # quadratic: each pod sees a different shard of the data
     key = jax.random.PRNGKey(0)
     targets = jax.random.normal(key, (4, 8))
     w0 = jnp.zeros((8,))
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(), P("pod"), P()),
-             out_specs=(P(), P()), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P("pod"), P()),
+             out_specs=(P(), P()), check_vma=False)
     def compressed_step(w, tgt, err):
         g = 2 * (w - tgt[0])                     # local gradient
         mean_g, new_err = ef_int8_allreduce_tree({"g": g}, {"g": err},
                                                  "pod")
         return mean_g["g"], new_err["g"]
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(), P("pod")), out_specs=P(),
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P("pod")), out_specs=P(),
+             check_vma=False)
     def exact_step(w, tgt):
         g = 2 * (w - tgt[0])
         return jax.lax.pmean(g, "pod")

@@ -73,7 +73,8 @@ def _sharded_vs_reference(backend: str) -> dict:
     exact_fn, exact_fn_p = exec_ns["exact_fn"], exec_ns["exact_fn_p"]
     wi, wo = exec_ns["wi"], exec_ns["wo"]
 
-    mesh = jax.make_mesh((DEVS,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((DEVS,), ("data",))
     y_sh, s_sh = jax.jit(lambda xx, lg: D.mcma_dispatch_sharded(
         mesh, xx, lg, exact_fn_p, (wi, wo), *w, exact_cap=EC, invoke_cap=IC,
         backend=backend, block_t=BLOCK, interpret=True))(x, logits)
@@ -149,7 +150,8 @@ _SHARDED = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.runtime import dispatch as DS
 """) + _CASE + textwrap.dedent("""
-    mesh = jax.make_mesh((DEVS,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((DEVS,), ("data",))
     out = {}
     for backend in ("xla", "pallas"):
         y_sh, s_sh = jax.jit(lambda xx, lg, be=backend:
@@ -233,7 +235,8 @@ _APPROX_MANUAL = textwrap.dedent("""
     # because routing is identical; generous caps avoid drop divergence)
     y1, a1 = approx_ffn_fwd(cfg, p, x, serve=True)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     xs = jax.device_put(x, NamedSharding(mesh, P(("data",), None, None)))
     out = {}
     ys = {}

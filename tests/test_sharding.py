@@ -90,7 +90,8 @@ _MULTIDEV = textwrap.dedent("""
 
     cfg = dataclasses.replace(smoke_config(get_config("internlm2-1.8b")),
                               d_model=64, n_heads=4, n_kv_heads=2, vocab=256)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     ds = SyntheticLM(vocab=cfg.vocab, seq_len=32, global_batch=8)
     state = S.init_train_state(jax.random.PRNGKey(0), cfg)
     specs, _ = R.state_pspecs(mesh, state)
@@ -140,7 +141,8 @@ _MOE_MANUAL = textwrap.dedent("""
 
     cfg = smoke_config(get_config("moonshot-v1-16b-a3b"))
     # 4 experts over a model axis of 2 -> manual EP path (E % md == 0)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     key = jax.random.PRNGKey(0)
     p = MOE.init_moe(key, cfg)
     x = jax.random.normal(jax.random.fold_in(key, 1), (8, 16, cfg.d_model),
