@@ -58,6 +58,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.scopes import SWITCH_KERNEL_FUSED
+
 
 def _pad_rows(n: int) -> int:
     """Round a scratch row count up to whole (8, 128) f32 tiles."""
@@ -202,4 +204,5 @@ def switched_mlp_fused(x: jax.Array, rows: jax.Array, tile_cls: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t + 1, d_out_p), x.dtype),
         interpret=interpret,
+        name=SWITCH_KERNEL_FUSED,
     )(rows, tile_cls, x, w1, b1, w2, b2)

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.scopes import SWITCH_KERNEL
+
 
 def _switched_kernel(tile_cls_ref, x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref):
     del tile_cls_ref  # consumed by the index_maps only
@@ -66,4 +68,5 @@ def switched_mlp(x: jax.Array, tile_cls: jax.Array, w1: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, d_out), x.dtype),
         interpret=interpret,
+        name=SWITCH_KERNEL,
     )(tile_cls, x, w1, b1, w2, b2)

@@ -31,6 +31,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.configs.base import ModelConfig
 from repro.kernels.ops import prepad_switched_weights
 from repro.models.layers import ffn_fwd, init_ffn
@@ -198,6 +199,7 @@ def _tier_args(cfg: ModelConfig, tier, tier_margins, s: int):
     return tr, tier_margins
 
 
+@jax.named_scope(scopes.MCMA_PLAN)
 def make_tick_plan(cfg: ModelConfig, params, x: jax.Array,
                    row_mask: jax.Array | None = None,
                    tier: jax.Array | None = None,

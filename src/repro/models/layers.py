@@ -14,6 +14,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.configs.base import ModelConfig
 
 
@@ -86,6 +87,7 @@ def embed_fwd(cfg: ModelConfig, p, tokens_or_embeds: jax.Array) -> jax.Array:
     return jnp.take(p["tok"], tokens_or_embeds, axis=0).astype(cfg.adtype)
 
 
+@jax.named_scope(scopes.UNEMBED)
 def unembed_fwd(cfg: ModelConfig, p, x: jax.Array) -> jax.Array:
     w = p["tok"].T if cfg.tie_embeddings else p["unembed"]
     return jnp.dot(x, w.astype(x.dtype))
@@ -222,6 +224,7 @@ def flash_attention(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
     return o.swapaxes(0, 1).reshape(b, sq, h, hd)
 
 
+@jax.named_scope(scopes.KV_GATHER)
 def _gather_pages(pool: jax.Array, block_table: jax.Array) -> jax.Array:
     """Gather a paged pool back into a per-slot dense view.
 
@@ -272,18 +275,20 @@ def _attention_chunk(cfg: ModelConfig, q, k, v, cache):
         pg_idx, within = jnp.divmod(qpos, page_size)
         pg = jnp.take_along_axis(bt, jnp.minimum(pg_idx, n_pp - 1), axis=1)
         pg = jnp.where(tok_ok & (pg_idx < n_pp) & (pg >= 0), pg, n_pages)
-        ck = cache["k"].at[pg, within].set(
-            k.astype(cache["k"].dtype), mode="drop")
-        cv = cache["v"].at[pg, within].set(
-            v.astype(cache["v"].dtype), mode="drop")
+        with jax.named_scope(scopes.KV_WRITE):
+            ck = cache["k"].at[pg, within].set(
+                k.astype(cache["k"].dtype), mode="drop")
+            cv = cache["v"].at[pg, within].set(
+                v.astype(cache["v"].dtype), mode="drop")
         ak, av = _gather_pages(ck, bt), _gather_pages(cv, bt)
         new_cache = {"k": ck, "v": cv, "block_table": bt, "pos": pos + nv}
     else:
         skv = cache["k"].shape[1]
         idx = jnp.where(tok_ok, qpos, skv)
         write = jax.vmap(lambda c, new, i: c.at[i].set(new, mode="drop"))
-        ck = write(cache["k"], k.astype(cache["k"].dtype), idx)
-        cv = write(cache["v"], v.astype(cache["v"].dtype), idx)
+        with jax.named_scope(scopes.KV_WRITE):
+            ck = write(cache["k"], k.astype(cache["k"].dtype), idx)
+            cv = write(cache["v"], v.astype(cache["v"].dtype), idx)
         ak, av = ck, cv
         new_cache = {"k": ck, "v": cv, "pos": pos + nv}
     valid = jnp.arange(skv)[None, None, :] <= qpos[:, :, None]  # (B, Sq, Skv)
@@ -298,6 +303,7 @@ def _attention_chunk(cfg: ModelConfig, q, k, v, cache):
     return o, new_cache
 
 
+@jax.named_scope(scopes.ATTENTION)
 def attention_fwd(cfg: ModelConfig, p, x: jax.Array, positions: jax.Array,
                   cache: dict | None = None):
     """Self-attention.  Without a cache: full-sequence flash attention
@@ -357,10 +363,11 @@ def attention_fwd(cfg: ModelConfig, p, x: jax.Array, positions: jax.Array,
             # index n_pages so mode="drop" discards the write instead of
             # corrupting a live page
             pg = jnp.where(pg < 0, n_pages, pg)
-            ck = cache["k"].at[pg, off].set(
-                k[:, 0].astype(cache["k"].dtype), mode="drop")
-            cv = cache["v"].at[pg, off].set(
-                v[:, 0].astype(cache["v"].dtype), mode="drop")
+            with jax.named_scope(scopes.KV_WRITE):
+                ck = cache["k"].at[pg, off].set(
+                    k[:, 0].astype(cache["k"].dtype), mode="drop")
+                cv = cache["v"].at[pg, off].set(
+                    v[:, 0].astype(cache["v"].dtype), mode="drop")
             ak, av = _gather_pages(ck, bt), _gather_pages(cv, bt)
             valid = jnp.arange(skv)[None, :] <= pos[:, None]
             new_cache = {"k": ck, "v": cv, "block_table": bt, "pos": pos + 1}
@@ -373,8 +380,9 @@ def attention_fwd(cfg: ModelConfig, p, x: jax.Array, positions: jax.Array,
             write = jax.vmap(
                 lambda c, new, i: jax.lax.dynamic_update_slice(
                     c, new, (i, 0, 0)))
-            ck = write(cache["k"], k.astype(cache["k"].dtype), slot)
-            cv = write(cache["v"], v.astype(cache["v"].dtype), slot)
+            with jax.named_scope(scopes.KV_WRITE):
+                ck = write(cache["k"], k.astype(cache["k"].dtype), slot)
+                cv = write(cache["v"], v.astype(cache["v"].dtype), slot)
             kpos = jnp.arange(skv)
             if cfg.sliding_window:
                 valid = (kpos[None, :] <= slot[:, None]) \

@@ -29,6 +29,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import mamba2, moe, xlstm
@@ -509,7 +510,8 @@ def decode(cfg: ModelConfig, params, cache, inputs: jax.Array, *,
         def body(carry, blk_i):
             x, ck, cv = carry
             blk, i = blk_i
-            lc = {"k": ck[i], "v": cv[i], "pos": pos}
+            with jax.named_scope(scopes.KV_GATHER):
+                lc = {"k": ck[i], "v": cv[i], "pos": pos}
             if bt is not None:
                 lc["block_table"] = bt
             x, nc, _, m = _dense_block(cfg, blk, x, positions, lc, serve=serve,
@@ -517,8 +519,9 @@ def decode(cfg: ModelConfig, params, cache, inputs: jax.Array, *,
                                        tier=tier, tier_margins=tier_margins,
                                        residency=residency)
             m.pop("_label_votes", None)   # train-only co-training signal
-            ck = jax.lax.dynamic_update_index_in_dim(ck, nc["k"], i, 0)
-            cv = jax.lax.dynamic_update_index_in_dim(cv, nc["v"], i, 0)
+            with jax.named_scope(scopes.KV_WRITE):
+                ck = jax.lax.dynamic_update_index_in_dim(ck, nc["k"], i, 0)
+                cv = jax.lax.dynamic_update_index_in_dim(cv, nc["v"], i, 0)
             return (x, ck, cv), (m if collect_metrics else None)
         (x, ks, vs), ms = jax.lax.scan(
             body, (x, cache["k"], cache["v"]),
@@ -564,15 +567,17 @@ def decode(cfg: ModelConfig, params, cache, inputs: jax.Array, *,
                 x, ns = _mamba_block(cfg, blk, x, st)
                 return x, ns
             x, nmsts = jax.lax.scan(inner, x, (mblks, msts))
-            lc = {"k": ck[gi], "v": cv[gi], "pos": pos}
+            with jax.named_scope(scopes.KV_GATHER):
+                lc = {"k": ck[gi], "v": cv[gi], "pos": pos}
             x, nc, _, m = _dense_block(cfg, shared, x, positions, lc,
                                        serve=serve, row_mask=row_mask,
                                        dispatch_plan=plan, tier=tier,
                                        tier_margins=tier_margins,
                                        residency=residency)
             m.pop("_label_votes", None)   # train-only co-training signal
-            ck = jax.lax.dynamic_update_index_in_dim(ck, nc["k"], gi, 0)
-            cv = jax.lax.dynamic_update_index_in_dim(cv, nc["v"], gi, 0)
+            with jax.named_scope(scopes.KV_WRITE):
+                ck = jax.lax.dynamic_update_index_in_dim(ck, nc["k"], gi, 0)
+                cv = jax.lax.dynamic_update_index_in_dim(cv, nc["v"], gi, 0)
             return (x, ck, cv), (nmsts, m if collect_metrics else None)
         (x, ks, vs), (nm, ms) = jax.lax.scan(
             group, (x, cache["k"], cache["v"]),
@@ -643,7 +648,8 @@ def decode_chunk(cfg: ModelConfig, params, cache, tokens: jax.Array,
     def body(carry, blk_i):
         x, ck, cv = carry
         blk, i = blk_i
-        lc = {"k": ck[i], "v": cv[i], "pos": pos, "n_valid": n_valid}
+        with jax.named_scope(scopes.KV_GATHER):
+            lc = {"k": ck[i], "v": cv[i], "pos": pos, "n_valid": n_valid}
         if bt is not None:
             lc["block_table"] = bt
         x, nc, _, m = _dense_block(cfg, blk, x, positions, lc, serve=serve,
@@ -651,8 +657,9 @@ def decode_chunk(cfg: ModelConfig, params, cache, tokens: jax.Array,
                                    tier=tier, tier_margins=tier_margins,
                                    residency=residency)
         m.pop("_label_votes", None)   # train-only co-training signal
-        ck = jax.lax.dynamic_update_index_in_dim(ck, nc["k"], i, 0)
-        cv = jax.lax.dynamic_update_index_in_dim(cv, nc["v"], i, 0)
+        with jax.named_scope(scopes.KV_WRITE):
+            ck = jax.lax.dynamic_update_index_in_dim(ck, nc["k"], i, 0)
+            cv = jax.lax.dynamic_update_index_in_dim(cv, nc["v"], i, 0)
         return (x, ck, cv), (m if collect_metrics else None)
     (_, ks, vs), ms = jax.lax.scan(
         body, (x, cache["k"], cache["v"]),

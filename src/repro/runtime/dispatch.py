@@ -76,6 +76,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.kernels import ops
 
 # Backends whose plans carry a live class-sort (order/pos/tile_cls) and
@@ -665,8 +666,10 @@ def execute_dispatch(plan: DispatchPlan, x: jax.Array,
         f"weights_prepadded={weights_prepadded}) does not match the plan's "
         f"n_approx={n}")
     # exact ("nC") rows: capacity gather -> exact_fn -> scatter-back
-    xg = scatter_rows(x, plan.exact_slot, plan.exact_keep, plan.exact_cap)
-    out = gather_rows(exact_fn(xg), plan.exact_slot, plan.exact_keep)
+    with jax.named_scope(scopes.EXACT_FFN):
+        xg = scatter_rows(x, plan.exact_slot, plan.exact_keep,
+                          plan.exact_cap)
+        out = gather_rows(exact_fn(xg), plan.exact_slot, plan.exact_keep)
 
     if plan.backend == "xla":
         d_out = out.shape[-1]

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import scopes
 from repro.configs.base import ModelConfig
 from repro.models import model as M
 from repro.runtime import steps as steps_lib
@@ -72,13 +73,16 @@ class Request:
     # unservable prompt that bypassed submit() validation.  ``done`` stays
     # False for stranded requests — aborted is the explicit signal.
     aborted: bool = False
-    # latency bookkeeping (bench_serve's raw TTFT signal).  arrival_* are
-    # stamped at submit(); first_token_* at the tick that sampled the
-    # request's first output token.  Ticks count BOTH phases, so a
-    # prefill-heavy schedule shows up in TTFT-in-ticks directly.
+    # latency bookkeeping.  arrival_* are stamped at submit(), admitted_s
+    # when the request takes a slot, first_token_* at the tick that
+    # sampled its first output token.  Ticks count BOTH phases, so a
+    # prefill-heavy schedule shows up in TTFT-in-ticks directly.  The *_s
+    # stamps are time.perf_counter() seconds: differences only, on the
+    # clock a caller timing its own submits and ticks uses too.
     arrival_tick: int | None = None
     first_token_tick: int | None = None
     arrival_s: float | None = None
+    admitted_s: float | None = None
     first_token_s: float | None = None
 
 
@@ -173,6 +177,10 @@ class DrainStats:
 
 
 _DRAIN_FIELDS = tuple(f.name for f in dataclasses.fields(DrainStats))
+
+# a host span of the tick's phases (repro/scopes.py); records nothing
+# unless a profiler is running
+_span = jax.profiler.TraceAnnotation
 
 # the historic DecodeServer.__init__ keyword surface (PRs 1-6) — every
 # name is also a ServeOptions field, so the shim is a dataclasses.replace
@@ -622,7 +630,7 @@ class DecodeServer:
                     f"{len(self.tier_bounds)} tiers {self.tier_bounds}")
             req.tier = int(req.tier)
         req.arrival_tick = self.ticks
-        req.arrival_s = time.time()
+        req.arrival_s = time.perf_counter()
         req._seq = self._submit_seq          # FIFO tiebreak under "cost"
         self._submit_seq += 1
         self.queue.append(req)
@@ -728,18 +736,21 @@ class DecodeServer:
                         return
                 self.queue.pop(j)
                 self.slots[i] = req
+                req.admitted_s = time.perf_counter()
                 self.remaining_prompt[i] = np.asarray(req.prompt, np.int32)
                 if self.page_size:
                     self._reserved[i] = need
                     self._reserved_total += need
-                if self._fresh is None:
-                    self._fresh = M.init_cache(self.cfg, self.batch,
-                                               self.max_len,
-                                               page_size=self.page_size,
-                                               kv_pages=self.n_pages)
-                    if self.mesh is not None:
-                        self._fresh = self._shard_cache(self._fresh)
-                self.cache = M.reset_slot(self.cfg, self.cache, self._fresh, i)
+                with _span(scopes.RESET_SLOT):
+                    if self._fresh is None:
+                        self._fresh = M.init_cache(self.cfg, self.batch,
+                                                   self.max_len,
+                                                   page_size=self.page_size,
+                                                   kv_pages=self.n_pages)
+                        if self.mesh is not None:
+                            self._fresh = self._shard_cache(self._fresh)
+                    self.cache = M.reset_slot(self.cfg, self.cache,
+                                              self._fresh, i)
                 break
 
     def _abort_unservable(self):
@@ -778,131 +789,150 @@ class DecodeServer:
         have n_valid 0 — the chunk step writes nothing for them and their
         ``pos`` holds."""
         S = self.prefill_chunk
-        toks = np.zeros((self.batch, S), np.int32)
-        nv = np.zeros((self.batch,), np.int32)
-        for i in rows:
-            n = min(S, self.remaining_prompt[i].size - 1)
-            toks[i, :n] = self.remaining_prompt[i][:n]
-            self.remaining_prompt[i] = self.remaining_prompt[i][n:]
-            nv[i] = n
-        if self.page_size:
+        with _span(scopes.PREPARE):
+            toks = np.zeros((self.batch, S), np.int32)
+            nv = np.zeros((self.batch,), np.int32)
             for i in rows:
-                self._ensure_slot_pages(i, int(self._pos_host[i]) + int(nv[i]))
-            self._sync_block_table()
-        args = [self.params, self.cache, jnp.asarray(toks), jnp.asarray(nv)]
-        if self.use_mcma_dispatch and self.tier_bounds is not None:
-            args += [None, jnp.asarray(self._tiers_arr()),
-                     jnp.asarray(self.tier_margins)]
-        self.cache, m = self._prefill(*args, **self._residency_kw())
-        if self.page_size:
-            for i in rows:
-                self._pos_host[i] += int(nv[i])
-        tokens = int(nv.sum())
-        inv = None
-        if self.use_mcma_dispatch and "invocation" in m:
-            inv = float(m["invocation"])
-            self.prefill_invocation_sum += inv * tokens
-        self.prefill_tokens += tokens
-        self.prefill_ticks += 1
-        self._log_tick("prefill", tokens, inv)
+                n = min(S, self.remaining_prompt[i].size - 1)
+                toks[i, :n] = self.remaining_prompt[i][:n]
+                self.remaining_prompt[i] = self.remaining_prompt[i][n:]
+                nv[i] = n
+            if self.page_size:
+                for i in rows:
+                    self._ensure_slot_pages(
+                        i, int(self._pos_host[i]) + int(nv[i]))
+                self._sync_block_table()
+            args = [self.params, self.cache, jnp.asarray(toks),
+                    jnp.asarray(nv)]
+            if self.use_mcma_dispatch and self.tier_bounds is not None:
+                args += [None, jnp.asarray(self._tiers_arr()),
+                         jnp.asarray(self.tier_margins)]
+            kw = self._residency_kw()
+        with _span(scopes.STEP):
+            self.cache, m = self._prefill(*args, **kw)
+        with _span(scopes.READ_STATS):
+            tokens = int(nv.sum())
+            inv = None
+            if self.use_mcma_dispatch and "invocation" in m:
+                inv = float(m["invocation"])
+                self.prefill_invocation_sum += inv * tokens
+            self.prefill_tokens += tokens
+            self.prefill_ticks += 1
+            self._log_tick("prefill", tokens, inv)
+        with _span(scopes.COMMIT):
+            if self.page_size:
+                for i in rows:
+                    self._pos_host[i] += int(nv[i])
 
     def _decode_tick(self, rows: list[int]):
         """One decode tick for the listed slots; every other slot is
         masked out (its ``pos`` holds — the row's dummy write is
         overwritten by its next real token)."""
-        toks = np.zeros((self.batch, 1), np.int32)
-        fed_prompt = [False] * self.batch
-        active = [False] * self.batch
-        for i in rows:
-            req = self.slots[i]
-            active[i] = True
-            if self.remaining_prompt[i].size:       # prompt-feeding phase
-                toks[i, 0] = self.remaining_prompt[i][0]
-                self.remaining_prompt[i] = self.remaining_prompt[i][1:]
-                fed_prompt[i] = True
-            elif req.out:
-                toks[i, 0] = req.out[-1]
-            else:
-                toks[i, 0] = req.prompt[-1]
-        if self.page_size:
-            # every listed row writes ONE token at its pos this tick —
-            # make sure the page covering it is allocated (lazy
-            # acquisition at the boundary crossing)
+        with _span(scopes.PREPARE):
+            toks = np.zeros((self.batch, 1), np.int32)
+            fed_prompt = [False] * self.batch
+            active = [False] * self.batch
             for i in rows:
-                self._ensure_slot_pages(i, int(self._pos_host[i]) + 1)
-            self._sync_block_table()
-        mask = jnp.asarray(active)
-        if self.use_mcma_dispatch:
-            # active-row mask: idle and mid-prefill slots are excluded
-            # from the dispatch and its stats inside the step (the
-            # free-slot bias fix), so every metric below is exact for the
-            # decoding slots only
-            if self.tier_bounds is not None:
-                # per-slot QoS tier vector, riding next to the mask; the
-                # margins vector is a traced input — one compiled step
-                # serves every tier mix
-                logits, self.cache, m = self._decode(
-                    self.params, self.cache, jnp.asarray(toks), mask,
-                    jnp.asarray(self._tiers_arr()),
-                    jnp.asarray(self.tier_margins),
-                    **self._residency_kw())
+                req = self.slots[i]
+                active[i] = True
+                if self.remaining_prompt[i].size:   # prompt-feeding phase
+                    toks[i, 0] = self.remaining_prompt[i][0]
+                    self.remaining_prompt[i] = self.remaining_prompt[i][1:]
+                    fed_prompt[i] = True
+                elif req.out:
+                    toks[i, 0] = req.out[-1]
+                else:
+                    toks[i, 0] = req.prompt[-1]
+            if self.page_size:
+                # every listed row writes ONE token at its pos this tick —
+                # make sure the page covering it is allocated (lazy
+                # acquisition at the boundary crossing)
+                for i in rows:
+                    self._ensure_slot_pages(i, int(self._pos_host[i]) + 1)
+                self._sync_block_table()
+            args = [self.params, self.cache, jnp.asarray(toks),
+                    jnp.asarray(active)]
+            kw = {}
+            if self.use_mcma_dispatch:
+                # active-row mask: idle and mid-prefill slots are excluded
+                # from the dispatch and its stats inside the step (the
+                # free-slot bias fix), so every metric below is exact for
+                # the decoding slots only
+                if self.tier_bounds is not None:
+                    # per-slot QoS tier vector, riding next to the mask;
+                    # the margins vector is a traced input — one compiled
+                    # step serves every tier mix
+                    args += [jnp.asarray(self._tiers_arr()),
+                             jnp.asarray(self.tier_margins)]
+                kw = self._residency_kw()
+        with _span(scopes.STEP):
+            out = self._decode(*args, **kw)
+            logits, self.cache = out[0], out[1]
+        with _span(scopes.READ_STATS):
+            if self.use_mcma_dispatch:
+                self._read_decode_stats(out[2], sum(active))
             else:
-                logits, self.cache, m = self._decode(self.params, self.cache,
-                                                     jnp.asarray(toks), mask,
-                                                     **self._residency_kw())
-            n_active = sum(active)
-            inv = None
-            if "invocation" in m:
-                inv = float(m["invocation"])
-                self.invocation_sum += inv * n_active
-                self.active_sum += n_active
-            if "dropped_rows" in m:
-                self.dropped_sum += float(m["dropped_rows"])
-                disp = np.asarray(m["dispatched"], float)
-                routed = np.asarray(m["class_counts"], float)
-                self.dispatched_sum = disp if self.dispatched_sum is None \
-                    else self.dispatched_sum + disp
-                self.routed_sum = routed if self.routed_sum is None \
-                    else self.routed_sum + routed
-                self.routed_history.append(routed)
-                if len(self.routed_history) > self.routed_history_cap:
-                    del self.routed_history[0]
-                if "tier_counts" in m:
-                    tc = np.asarray(m["tier_counts"], float)
-                    td = np.asarray(m["tier_dispatched"], float)
-                    self.tier_routed_sum = tc \
-                        if self.tier_routed_sum is None \
-                        else self.tier_routed_sum + tc
-                    self.tier_dispatched_sum = td \
-                        if self.tier_dispatched_sum is None \
-                        else self.tier_dispatched_sum + td
-                if self.controller is not None:
-                    self.controller.observe(
-                        {"class_counts": routed, "dropped": m["dropped_rows"]})
-                if self.residency_controller is not None \
-                        and "lib_counts" in m:
-                    # full-library demand histogram: feed the residency
-                    # controller and adopt whatever hot set it returns —
-                    # the next tick's step call carries the new vector
-                    # through the SAME compiled program (zero retraces)
-                    lib = np.asarray(m["lib_counts"], float)
-                    self.lib_routed_sum = lib \
-                        if self.lib_routed_sum is None \
-                        else self.lib_routed_sum + lib
-                    self.off_set_sum += float(m["off_set_exact_rows"])
-                    self.residency = np.asarray(
-                        self.residency_controller.observe(
-                            {"lib_counts": lib}), np.int32)
-            self._log_tick("decode", n_active, inv)
-        else:
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              jnp.asarray(toks), mask)
-            self._log_tick("decode", sum(active), None)
-        if self.greedy:
-            nxt = np.asarray(jnp.argmax(logits, -1))
-        else:
-            self.key, k = jax.random.split(self.key)
-            nxt = np.asarray(jax.random.categorical(k, logits))
+                self._log_tick("decode", sum(active), None)
+        with _span(scopes.SAMPLE):
+            if self.greedy:
+                nxt = np.asarray(jnp.argmax(logits, -1))
+            else:
+                self.key, k = jax.random.split(self.key)
+                nxt = np.asarray(jax.random.categorical(k, logits))
+        with _span(scopes.COMMIT):
+            self._commit_decode(rows, nxt, fed_prompt)
+
+    def _read_decode_stats(self, m: dict, n_active: int):
+        """Fold one decode step's dispatch metrics into the server's sums
+        and controllers (each read blocks on the step)."""
+        inv = None
+        if "invocation" in m:
+            inv = float(m["invocation"])
+            self.invocation_sum += inv * n_active
+            self.active_sum += n_active
+        if "dropped_rows" in m:
+            self.dropped_sum += float(m["dropped_rows"])
+            disp = np.asarray(m["dispatched"], float)
+            routed = np.asarray(m["class_counts"], float)
+            self.dispatched_sum = disp if self.dispatched_sum is None \
+                else self.dispatched_sum + disp
+            self.routed_sum = routed if self.routed_sum is None \
+                else self.routed_sum + routed
+            self.routed_history.append(routed)
+            if len(self.routed_history) > self.routed_history_cap:
+                del self.routed_history[0]
+            if "tier_counts" in m:
+                tc = np.asarray(m["tier_counts"], float)
+                td = np.asarray(m["tier_dispatched"], float)
+                self.tier_routed_sum = tc \
+                    if self.tier_routed_sum is None \
+                    else self.tier_routed_sum + tc
+                self.tier_dispatched_sum = td \
+                    if self.tier_dispatched_sum is None \
+                    else self.tier_dispatched_sum + td
+            if self.controller is not None:
+                self.controller.observe(
+                    {"class_counts": routed, "dropped": m["dropped_rows"]})
+            if self.residency_controller is not None \
+                    and "lib_counts" in m:
+                # full-library demand histogram: feed the residency
+                # controller and adopt whatever hot set it returns —
+                # the next tick's step call carries the new vector
+                # through the SAME compiled program (zero retraces)
+                lib = np.asarray(m["lib_counts"], float)
+                self.lib_routed_sum = lib \
+                    if self.lib_routed_sum is None \
+                    else self.lib_routed_sum + lib
+                self.off_set_sum += float(m["off_set_exact_rows"])
+                self.residency = np.asarray(
+                    self.residency_controller.observe(
+                        {"lib_counts": lib}), np.int32)
+        self._log_tick("decode", n_active, inv)
+
+    def _commit_decode(self, rows: list[int], nxt: np.ndarray,
+                       fed_prompt: list[bool]):
+        """Append each listed row's sampled token, stamp first tokens, and
+        finish and release the requests that are done."""
         pos = np.asarray(self.cache["pos"])           # (B,) per-slot
         if self.page_size:
             for i in rows:
@@ -919,7 +949,7 @@ class DecodeServer:
             req.out.append(int(nxt[i]))
             if req.first_token_tick is None:
                 req.first_token_tick = self.ticks + 1   # tick about to close
-                now = time.time() if now is None else now
+                now = time.perf_counter() if now is None else now
                 req.first_token_s = now
             if (self.eos is not None and req.out[-1] == self.eos) \
                     or len(req.out) >= req.max_new \
@@ -939,28 +969,32 @@ class DecodeServer:
         scheduler alternates them, so queued prompts load S tokens per
         prefill tick while in-flight decodes keep streaming (neither
         phase can starve the other)."""
-        self._admit()
-        self._abort_unservable()
-        if not any(s is not None for s in self.slots):
-            return False
-        prefill_rows = self._prefill_rows() if self.prefill_chunk else []
-        decode_rows = [i for i, s in enumerate(self.slots)
-                       if s is not None and i not in prefill_rows]
-        if prefill_rows and (not decode_rows or not self._phase_flip):
-            self._phase_flip = True
-            self._prefill_tick(prefill_rows)
-        else:
-            self._phase_flip = False
-            self._decode_tick(decode_rows)
-        self.ticks += 1
-        if self.page_size:
-            # page_util's raw signal: tokens actually held vs the token
-            # capacity of the pages holding them, sampled once per tick
-            self._held_token_ticks += int(sum(
-                self._pos_host[i] for i in range(self.batch)
-                if self._slot_pages[i]))
-            self._held_page_ticks += self.pages_in_use
-        return True
+        with _span(scopes.TICK):
+            with _span(scopes.ADMIT):
+                self._admit()
+            with _span(scopes.ABORT_CHECK):
+                self._abort_unservable()
+            if not any(s is not None for s in self.slots):
+                return False
+            prefill_rows = self._prefill_rows() if self.prefill_chunk else []
+            decode_rows = [i for i, s in enumerate(self.slots)
+                           if s is not None and i not in prefill_rows]
+            if prefill_rows and (not decode_rows or not self._phase_flip):
+                self._phase_flip = True
+                self._prefill_tick(prefill_rows)
+            else:
+                self._phase_flip = False
+                self._decode_tick(decode_rows)
+            self.ticks += 1
+            if self.page_size:
+                # page_util's raw signal: tokens actually held vs the
+                # token capacity of the pages holding them, sampled once
+                # per tick
+                self._held_token_ticks += int(sum(
+                    self._pos_host[i] for i in range(self.batch)
+                    if self._slot_pages[i]))
+                self._held_page_ticks += self.pages_in_use
+            return True
 
     def run_until_drained(self, max_ticks: int = 10_000) -> DrainStats:
         """Tick until queue and slots are empty (or ``max_ticks``); returns

@@ -1,0 +1,87 @@
+"""The program's names for its own work in a profiler trace
+(repro/scopes.py): every device scope and kernel name is on the op paths
+of the compiled served steps, which is where a trace reads them, and a
+request's stamps are on ``time.perf_counter()`` and in order."""
+import dataclasses
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro import scopes
+from repro.configs.registry import get_config, smoke_config
+from repro.models import model as M
+from repro.runtime.options import ServeOptions
+from repro.runtime.server import DecodeServer, Request
+
+jax.config.update("jax_platform_name", "cpu")
+
+BATCH, MAX_LEN, PAGE, CHUNK = 2, 64, 8, 8
+
+
+def _server(backend: str = "pallas") -> DecodeServer:
+    cfg = smoke_config(get_config("internlm2-1.8b"))
+    cfg = dataclasses.replace(cfg, approx=dataclasses.replace(
+        cfg.approx, enable=True, interpret=True))
+    params = M.init_model(jax.random.PRNGKey(0), cfg)
+    return DecodeServer(cfg, params, options=ServeOptions(
+        batch=BATCH, max_len=MAX_LEN, use_mcma_dispatch=True,
+        route_scope="tick", kv_page_size=PAGE, prefill_chunk=CHUNK,
+        backend=backend))
+
+
+def _op_paths(srv: DecodeServer, step: str) -> list[str]:
+    """The op path (``op_name`` metadata) of every compiled operation."""
+    if step == "decode":
+        lowered = srv.decode.lower(srv.params, srv.cache,
+                                   jnp.zeros((BATCH, 1), jnp.int32),
+                                   jnp.ones((BATCH,), bool))
+    else:
+        lowered = srv.chunk.lower(srv.params, srv.cache,
+                                  jnp.zeros((BATCH, CHUNK), jnp.int32),
+                                  jnp.full((BATCH,), CHUNK, jnp.int32))
+    return re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+
+
+@pytest.mark.parametrize("backend,kernel", [
+    ("pallas", scopes.SWITCH_KERNEL),
+    ("pallas_fused", scopes.SWITCH_KERNEL_FUSED)])
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_scope_and_kernel_names_are_in_the_lowered_step(step, backend,
+                                                        kernel):
+    paths = _op_paths(_server(backend), step)
+    # a chunk step samples nothing, so it has no unembed
+    want = [s for s in scopes.DEVICE_SCOPES
+            if step == "decode" or s != scopes.UNEMBED]
+    for name in want:
+        assert any(f"/{name}/" in p for p in paths), name
+    assert any(f"/{kernel}/" in p for p in paths)
+    if step == "chunk":
+        assert not any(f"/{scopes.UNEMBED}/" in p for p in paths)
+
+
+def test_the_kv_scopes_nest_inside_attention():
+    paths = _op_paths(_server(), "decode")
+    for kv in (scopes.KV_WRITE, scopes.KV_GATHER):
+        assert any(f"/{scopes.ATTENTION}/{kv}/" in p for p in paths), kv
+
+
+def test_request_stamps_are_ordered_on_the_perf_counter():
+    srv = _server()
+    rng = np.random.default_rng(0)
+    t_before = time.perf_counter()
+    reqs = [Request(rid=i, prompt=rng.integers(0, 512, n, dtype=np.int32),
+                    max_new=3) for i, n in enumerate((5, 19, 11))]
+    for r in reqs:
+        srv.submit(r)
+    srv.run_until_drained()
+    t_after = time.perf_counter()
+    for r in reqs:
+        assert r.done and not r.aborted
+        assert t_before <= r.arrival_s <= r.admitted_s <= r.first_token_s \
+            <= t_after, (r.arrival_s, r.admitted_s, r.first_token_s)
+    # three requests, two slots: the last one waited for a slot
+    assert max(r.admitted_s - r.arrival_s for r in reqs) > 0
