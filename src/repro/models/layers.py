@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro import scopes
 from repro.configs.base import ModelConfig
+from repro.kernels.paged_attention import paged_attention
 
 
 # ---------------------------------------------------------------------------
@@ -303,6 +304,26 @@ def _attention_chunk(cfg: ModelConfig, q, k, v, cache):
     return o, new_cache
 
 
+def _attention_paged_in_place(cfg: ModelConfig, p, q, k, v, cache, pg, off):
+    """Paged decode attention on the layer STACK (``cache["layer"]`` set,
+    k/v ``(L, N, P, Kh, hd)``): the step's K/V scatter into the stack at
+    ``[layer, pg, off]`` (``pg`` already remapped so holes drop), and the
+    paged kernel reads each slot's first ``cache["lengths"]`` tokens from
+    the stack in place — no layer pool is sliced out or written back and
+    no per-slot window is gathered."""
+    b, i = q.shape[0], cache["layer"]
+    with jax.named_scope(scopes.KV_WRITE):
+        ck = cache["k"].at[i, pg, off].set(
+            k[:, 0].astype(cache["k"].dtype), mode="drop")
+        cv = cache["v"].at[i, pg, off].set(
+            v[:, 0].astype(cache["v"].dtype), mode="drop")
+    o = paged_attention(q[:, 0], ck, cv, i, cache["block_table"],
+                        cache["lengths"], interpret=cfg.approx.interpret)
+    o = o.reshape(b, 1, cfg.n_heads * cfg.hd)
+    return jnp.dot(o, p["wo"].astype(o.dtype)), dict(cache, k=ck, v=cv,
+                                                     pos=cache["pos"] + 1)
+
+
 @jax.named_scope(scopes.ATTENTION)
 def attention_fwd(cfg: ModelConfig, p, x: jax.Array, positions: jax.Array,
                   cache: dict | None = None):
@@ -315,7 +336,10 @@ def attention_fwd(cfg: ModelConfig, p, x: jax.Array, positions: jax.Array,
     on either path: writes scatter into the slot's pages with
     ``mode="drop"`` and attention gathers the pages back into the dense
     per-slot view — the block table is a TRACED input, so page
-    allocation changes never retrace.
+    allocation changes never retrace.  A paged decode cache that also
+    carries ``layer`` (and ``lengths``) holds the whole layer stack: the
+    write lands in the stack and the paged kernel reads the held pages
+    in place (``_attention_paged_in_place``).
 
     Returns (out, new_cache).
     """
@@ -353,7 +377,8 @@ def attention_fwd(cfg: ModelConfig, p, x: jax.Array, positions: jax.Array,
             assert not cfg.sliding_window, \
                 "paged KV caches need absolute positions (no ring buffers)"
             bt = cache["block_table"]
-            n_pages, page_size = cache["k"].shape[0], cache["k"].shape[1]
+            # (N, P, Kh, hd) layer pool, or the (L, N, P, Kh, hd) stack
+            n_pages, page_size = cache["k"].shape[-4:-2]
             skv = bt.shape[1] * page_size
             pg_idx, off = jnp.divmod(pos, page_size)
             pg = jnp.take_along_axis(
@@ -363,6 +388,9 @@ def attention_fwd(cfg: ModelConfig, p, x: jax.Array, positions: jax.Array,
             # index n_pages so mode="drop" discards the write instead of
             # corrupting a live page
             pg = jnp.where(pg < 0, n_pages, pg)
+            if "layer" in cache:
+                return _attention_paged_in_place(cfg, p, q, k, v, cache, pg,
+                                                 off)
             with jax.named_scope(scopes.KV_WRITE):
                 ck = cache["k"].at[pg, off].set(
                     k[:, 0].astype(cache["k"].dtype), mode="drop")

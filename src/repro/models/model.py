@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from repro import scopes
 from repro.configs.base import ModelConfig
+from repro.kernels.paged_attention import held_lengths
 from repro.models import layers as L
 from repro.models import mamba2, moe, xlstm
 from repro.models.approx_ffn import approx_ffn_fwd, init_approx_ffn
@@ -443,6 +444,19 @@ def pad_cache(cfg: ModelConfig, cache, max_len: int):
     return dict(cache, k=grow(cache["k"]), v=grow(cache["v"]))
 
 
+def _pages_read_in_place(cfg: ModelConfig) -> bool:
+    """Whether a paged decode step attends with the paged kernel
+    (kernels/paged_attention.py) on the layer stack: under a Pallas
+    dispatch backend, off a mesh.  The ``xla`` backend (the oracle) keeps
+    the gathered path, bit-identical to a dense cache; so does a mesh
+    server, where a ``pallas_call`` left to the partitioner would gather
+    the replicated pool onto every device."""
+    from repro.runtime.dispatch import PALLAS_BACKENDS
+    from repro.sharding.activations import manual_dp_context
+    return (cfg.approx.backend in PALLAS_BACKENDS
+            and manual_dp_context()[0] is None)
+
+
 def decode(cfg: ModelConfig, params, cache, inputs: jax.Array, *,
            serve: bool = True, collect_metrics: bool = False,
            row_mask: jax.Array | None = None,
@@ -479,7 +493,13 @@ def decode(cfg: ModelConfig, params, cache, inputs: jax.Array, *,
     routing covers the full library, the residency map folds classes onto
     resident slots, and every layer executes against the residency-
     gathered weight rows.  A hot-set swap between ticks is a new vector
-    through this same compiled step — zero retraces."""
+    through this same compiled step — zero retraces.
+
+    A paged cache under a Pallas dispatch backend, off a mesh
+    (``_pages_read_in_place``): every layer scatters its K/V into the
+    carried ``(L, N, P, Kh, hd)`` stack and the paged attention kernel
+    reads each slot's held pages there, up to ``pos`` (a masked slot
+    reads none); no layer pool is sliced out or written back."""
     topo = topology(cfg)
     x = L.embed_fwd(cfg, params["embed"], inputs)
     pos = cache["pos"]                                   # (B,) per-slot
@@ -506,22 +526,35 @@ def decode(cfg: ModelConfig, params, cache, inputs: jax.Array, *,
         # it as scan xs/ys would materialize two extra (L, B, S, KV, hd)
         # temporaries, which at 32k context is the whole HBM budget.
         bt = cache.get("block_table")     # paged: ONE table for all layers
+        in_place = bt is not None and _pages_read_in_place(cfg)
+        if in_place:
+            # tokens each slot attends; a masked slot fetches nothing
+            lengths = held_lengths(pos, bt, cache["k"].shape[2])
+            if row_mask is not None:
+                lengths = jnp.where(row_mask, lengths, 0)
 
         def body(carry, blk_i):
             x, ck, cv = carry
             blk, i = blk_i
-            with jax.named_scope(scopes.KV_GATHER):
-                lc = {"k": ck[i], "v": cv[i], "pos": pos}
-            if bt is not None:
-                lc["block_table"] = bt
+            if in_place:
+                lc = {"k": ck, "v": cv, "pos": pos, "block_table": bt,
+                      "layer": i, "lengths": lengths}
+            else:
+                with jax.named_scope(scopes.KV_GATHER):
+                    lc = {"k": ck[i], "v": cv[i], "pos": pos}
+                if bt is not None:
+                    lc["block_table"] = bt
             x, nc, _, m = _dense_block(cfg, blk, x, positions, lc, serve=serve,
                                        row_mask=row_mask, dispatch_plan=plan,
                                        tier=tier, tier_margins=tier_margins,
                                        residency=residency)
             m.pop("_label_votes", None)   # train-only co-training signal
-            with jax.named_scope(scopes.KV_WRITE):
-                ck = jax.lax.dynamic_update_index_in_dim(ck, nc["k"], i, 0)
-                cv = jax.lax.dynamic_update_index_in_dim(cv, nc["v"], i, 0)
+            if in_place:
+                ck, cv = nc["k"], nc["v"]
+            else:
+                with jax.named_scope(scopes.KV_WRITE):
+                    ck = jax.lax.dynamic_update_index_in_dim(ck, nc["k"], i, 0)
+                    cv = jax.lax.dynamic_update_index_in_dim(cv, nc["v"], i, 0)
             return (x, ck, cv), (m if collect_metrics else None)
         (x, ks, vs), ms = jax.lax.scan(
             body, (x, cache["k"], cache["v"]),

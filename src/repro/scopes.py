@@ -13,9 +13,12 @@ than spelling them.
 
 # device scopes, inside the decode and prefill-chunk steps
 KV_WRITE = "kv_write"          # the step's K/V into the layer's pages, and
-                               # the layer's pool back into the stack
+                               # the layer's pool back into the stack (a
+                               # decode step that reads its pages in
+                               # place: the scatter into the stack alone)
 KV_GATHER = "kv_gather"        # the layer's pool out of the stack, and its
-                               # pages gathered into a per-slot view
+                               # pages gathered into a per-slot view (none
+                               # in a decode step that reads in place)
 ATTENTION = "attention"        # q/k/v, rope, scores, softmax, wo (the KV
                                # scopes nest inside it)
 MCMA_PLAN = "mcma_plan"        # tick router, route, class sort, capacities
@@ -28,6 +31,10 @@ DEVICE_SCOPES = (KV_WRITE, KV_GATHER, ATTENTION, MCMA_PLAN, EXACT_FFN,
 SWITCH_KERNEL = "mcma_switched_mlp"
 SWITCH_KERNEL_FUSED = "mcma_switched_mlp_fused"
 SWITCH_KERNELS = (SWITCH_KERNEL, SWITCH_KERNEL_FUSED)
+
+# the paged decode attention kernel's pallas_call name (called inside
+# ATTENTION, outside the KV scopes)
+PAGED_ATTENTION = "paged_decode_attention"
 
 # host spans: the root of one DecodeServer.tick and its children
 TICK = "serve.tick"

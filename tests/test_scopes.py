@@ -53,20 +53,38 @@ def _op_paths(srv: DecodeServer, step: str) -> list[str]:
 def test_scope_and_kernel_names_are_in_the_lowered_step(step, backend,
                                                         kernel):
     paths = _op_paths(_server(backend), step)
-    # a chunk step samples nothing, so it has no unembed
-    want = [s for s in scopes.DEVICE_SCOPES
-            if step == "decode" or s != scopes.UNEMBED]
-    for name in want:
-        assert any(f"/{name}/" in p for p in paths), name
+    # a chunk step samples nothing, so it has no unembed; a decode step
+    # under a Pallas backend reads its pages in place, so it gathers
+    # nothing and runs the paged attention kernel instead
+    absent = {"chunk": (scopes.UNEMBED,),
+              "decode": (scopes.KV_GATHER,)}[step]
+    for name in scopes.DEVICE_SCOPES:
+        assert any(f"/{name}/" in p for p in paths) != (name in absent), \
+            name
     assert any(f"/{kernel}/" in p for p in paths)
-    if step == "chunk":
-        assert not any(f"/{scopes.UNEMBED}/" in p for p in paths)
+    assert any(f"/{scopes.PAGED_ATTENTION}/" in p for p in paths) \
+        == (step == "decode")
 
 
 def test_the_kv_scopes_nest_inside_attention():
-    paths = _op_paths(_server(), "decode")
-    for kv in (scopes.KV_WRITE, scopes.KV_GATHER):
-        assert any(f"/{scopes.ATTENTION}/{kv}/" in p for p in paths), kv
+    srv = _server()
+    for step, kvs in (("decode", (scopes.KV_WRITE,)),
+                      ("chunk", (scopes.KV_WRITE, scopes.KV_GATHER))):
+        paths = _op_paths(srv, step)
+        for kv in kvs:
+            assert any(f"/{scopes.ATTENTION}/{kv}/" in p for p in paths), kv
+
+
+def test_the_paged_kernel_sits_under_attention_outside_the_kv_scopes():
+    """Where the trace reader finds it: the kernel's time goes to
+    ``attention`` and ``kv_*`` keep only the scatter of the step's K/V."""
+    paths = [p for p in _op_paths(_server(), "decode")
+             if f"/{scopes.PAGED_ATTENTION}/" in p]
+    assert paths
+    for p in paths:
+        parts = p.split("/")
+        assert scopes.ATTENTION in parts
+        assert scopes.KV_WRITE not in parts and scopes.KV_GATHER not in parts
 
 
 def test_request_stamps_are_ordered_on_the_perf_counter():

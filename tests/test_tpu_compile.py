@@ -18,12 +18,14 @@ test worker imports this file.
 from __future__ import annotations
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import scopes
 from repro.configs.registry import get_config
 from repro.kernels import fused_dispatch, ops, switched_mlp
 from repro.models import model as M
@@ -119,7 +121,9 @@ def test_served_step_compiles_for_v5e(one_chip, no_persistent_cache,
     paged cache, cut to 2 layers: the layers are scanned, so the compile
     barely depends on depth.  The cfg carries the compiled kernels
     directly (``interpret=False``); ``mcma_serve_config`` would pick
-    interpret mode here because this process sees only a CPU."""
+    interpret mode here because this process sees only a CPU.  The
+    decode step also reads its KV pages in place (the paged attention
+    kernel, no whole-pool copy or slice)."""
     cfg = get_config("internlm2-1.8b")
     cfg = dataclasses.replace(cfg, n_layers=2, approx=dataclasses.replace(
         cfg.approx, enable=True, backend=backend, interpret=False,
@@ -141,3 +145,28 @@ def test_served_step_compiles_for_v5e(one_chip, no_persistent_cache,
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, cache, *args).compile()
     _check(compiled)
+    if step == "decode":
+        _check_pages_read_in_place(compiled.as_text(), cfg.n_layers,
+                                   cfg.n_kv_heads, cfg.hd)
+
+
+# operations that would move a whole KV pool or stack
+_MOVES = ("copy", "copy-start", "copy-done", "dynamic-slice",
+          "dynamic-update-slice", "slice", "concatenate", "custom-call")
+
+
+def _check_pages_read_in_place(hlo: str, n_layers: int, kh: int, hd: int):
+    """The decode step runs the paged attention kernel, and nothing in it
+    (fused computations included) produces a layer's ``(N, P, Kh, hd)``
+    pool or copies or slices the ``(L, N, P, Kh, hd)`` stack: the step's
+    K/V scatter into the stack in place and the kernel reads it there."""
+    kernel = [ln for ln in hlo.splitlines()
+              if re.search(rf"%{scopes.PAGED_ATTENTION}[.\d]* = ", ln)]
+    assert kernel and all('custom_call_target="tpu_custom_call"' in ln
+                          for ln in kernel), kernel
+    stack = f"{n_layers},{N_PAGES},{PAGE},{kh},{hd}"
+    pool = f"{N_PAGES},{PAGE},{kh},{hd}"
+    for m in re.finditer(r"= \(?\w+\[([\d,]+)\]\S*\)? ([\w-]+)\(", hlo):
+        shape, op = m.groups()
+        assert shape != pool, m.group(0)
+        assert not (shape == stack and op in _MOVES), m.group(0)
