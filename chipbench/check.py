@@ -1,5 +1,23 @@
 """The comparison that decides ``correct``: what the timed path served
-against the plain reference (``chipbench/reference.py``).
+against the plain reference that the configuration names.
+
+A configuration file's ``reference`` key names a file,
+``chipbench/references/<name>.py``, that binds ``Reference``
+(``run.reference_of`` loads it).  A ``Reference`` keeps this contract:
+
+  * ``Reference(conf, key, *, control=None)``: ``conf`` is the
+    configuration file, ``key`` the PRNG key of the run's seed, from which
+    it makes its own weights; ``control`` ("fp8", "int8") computes every
+    weight matmul one precision below the configuration's;
+  * ``routing()``: per token id, the class its router picks (0 the exact
+    FFN, c >= 1 approximator c), the other of its two best classes, and
+    whether those two tie within the program's rounding: three arrays
+    over the vocabulary, as ``replay`` reads them;
+  * ``hidden(tokens, modes)``: the final normed hidden states (R, L, d) of
+    token rows (R, L), given per position the FFN each ran: ``EXACT``,
+    c >= 1 approximator c, or ``DROPPED`` (none, over capacity);
+  * ``logit_stats(h, ids)``: per position of ``hidden``'s output, the best
+    logit, the logit of ``ids`` (R, L), and the id the logits put first.
 
 After the window, a sample of the finished requests, drawn from the seed
 and holding the one with the most served tokens, is run through the
@@ -33,7 +51,7 @@ import dataclasses
 
 import numpy as np
 
-from chipbench.reference import DROPPED, Reference
+EXACT, DROPPED = 0, -1          # FFN modes beside approximator c >= 1
 
 
 def capacity(rows: int, frac: float) -> int:
@@ -222,9 +240,10 @@ def check_served(conf: dict, spec: dict, seed: int, served, *,
     first at each position stand for the served tokens, and the same
     numbers go through the same ``verdict``.  Its result is under
     ``"controls"``; the benchmark's runs never compute it."""
-    from chipbench.run import model_key
+    from chipbench.run import model_key, reference_of
+    reference = reference_of(conf)
     vocab, max_len = conf["model"]["vocab"], conf["serve"]["max_len"]
-    ref = Reference(conf, model_key(seed))
+    ref = reference(conf, model_key(seed))
     rep = replay(served.ticks, ref.routing(), conf["approx"])
     chosen = sample(served.stamps, seed, int(spec["check_requests"]),
                     rep.ambiguous)
@@ -250,7 +269,7 @@ def check_served(conf: dict, spec: dict, seed: int, served, *,
     for kind in controls:
         ids = want
         if served_any:
-            ctl = Reference(conf, model_key(seed), control=kind)
+            ctl = reference(conf, model_key(seed), control=kind)
             _, _, first = ctl.logit_stats(ctl.hidden(tokens, modes),
                                           np.maximum(want, 0))
             ids = np.asarray(first)
@@ -264,7 +283,7 @@ def check_served(conf: dict, spec: dict, seed: int, served, *,
     return out
 
 
-def widest_gap(ref: Reference, h, ids: np.ndarray, want: np.ndarray):
+def widest_gap(ref, h, ids: np.ndarray, want: np.ndarray):
     """Widest gap between the reference's best logit and its logit for
     ``ids``, over the positions that served a token (``want`` >= 0)."""
     best, got, _ = ref.logit_stats(h, np.maximum(ids, 0))

@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-EXACT, DROPPED = 0, -1
+from chipbench.check import EXACT
 
 
 def _normal(key, shape, scale):

@@ -4,7 +4,8 @@
         --seconds 40 --trace 0
 
 The cell is looked up by name in ``BENCHMARK.json``: its configuration
-file (``chipbench/configs/<config>.json``), its traffic mix
+file (``chipbench/configs/<config>.json``), the plain reference that file
+names (``chipbench/references/<reference>.py``), its traffic mix
 (``chipbench/traffic/<mix>.json``) and its metrics, each computed by the
 reader ``chipbench/metrics/<metric>.py``.  Weights are made on the device
 from ``--seed``; the traffic is generated from it.  Set-up (start of this
@@ -34,9 +35,11 @@ import gc
 import importlib.util
 import json
 import os
+import re
 import shutil
 import sys
 import tempfile
+import typing
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -48,6 +51,7 @@ from chipbench import traffic as traffic_lib  # noqa: E402
 
 CONFIG_DIR = ROOT / "chipbench" / "configs"
 METRIC_DIR = ROOT / "chipbench" / "metrics"
+REFERENCE_DIR = ROOT / "chipbench" / "references"
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_SECONDS = 6.0
 
@@ -107,12 +111,33 @@ def metrics_for(bench: dict, workload: str, trace: bool) -> list[dict]:
             if workload in m.get("workloads", [workload])]
 
 
-def reader(name: str):
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name}", METRIC_DIR / f"{name}.py")
+def _load(path: Path, module: str):
+    spec = importlib.util.spec_from_file_location(module, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(name: str):
+    return _load(METRIC_DIR / f"{name}.py", f"chipbench_metric_{name}").read
+
+
+_NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
+
+
+def reference_of(conf: dict):
+    """The ``Reference`` class bound in ``chipbench/references/<name>.py``,
+    ``<name>`` being the configuration's ``reference``.  A missing,
+    malformed or unknown name stops the run here, before anything is
+    served.  The contract a ``Reference`` keeps is in ``check.py``."""
+    name = conf.get("reference")
+    path = REFERENCE_DIR / f"{name}.py"
+    if not isinstance(name, str) or not _NAME.fullmatch(name) \
+            or not path.is_file():
+        raise SystemExit(f"run: configuration {conf.get('name')!r} names "
+                         f"reference {name!r}, which is no file <name>.py "
+                         f"in {REFERENCE_DIR}")
+    return _load(path, f"chipbench_reference_{name}").Reference
 
 
 def model_key(seed: int):
@@ -123,9 +148,24 @@ def model_key(seed: int):
     return jax.random.fold_in(key, seed >> 32) if seed >> 32 else key
 
 
+def _frozen(cls, fields: dict):
+    """``cls(**fields)`` with what JSON cannot say made so: a dict for a
+    field whose type is a dataclass becomes that dataclass, and a list
+    becomes a tuple, so that the result can be hashed."""
+    types = typing.get_type_hints(cls)
+
+    def frozen(k, v):
+        if isinstance(v, dict) and dataclasses.is_dataclass(types.get(k)):
+            return _frozen(types[k], v)
+        return tuple(frozen(None, x) for x in v) if isinstance(v, list) else v
+    return cls(**{k: frozen(k, v) for k, v in fields.items()})
+
+
 def model_config(conf: dict):
-    from repro.configs.base import ApproxConfig, ModelConfig
-    return ModelConfig(**conf["model"], approx=ApproxConfig(**conf["approx"]))
+    """The program's ``ModelConfig`` of a configuration file: its
+    ``model`` section with ``approx`` beside it."""
+    from repro.configs.base import ModelConfig
+    return _frozen(ModelConfig, dict(conf["model"], approx=conf["approx"]))
 
 
 def serve_options(conf: dict):
@@ -243,6 +283,7 @@ def measure(conf: dict, spec: dict, metric_names: list[dict], *, seed: int,
     """Everything of a run but the look for a chip: serve, read the
     metrics, check the outputs.  Returns the result object."""
     from chipbench import check, trace as trace_lib
+    reference_of(conf)           # a wrong name stops the run before serving
     served = serve_cell(conf, spec, seed=seed, seconds=seconds, trace=trace,
                         t_start=t_start, clock=clock)
     gc.collect()
