@@ -104,12 +104,16 @@ class SSMConfig:
     head_dim: int = 64           # Mamba2 P (state head dim)
     chunk: int = 256             # SSD / mLSTM chunk length
     slstm_every: int = 8         # xLSTM: one sLSTM block per this many blocks
+    # Mamba2's causal depthwise conv over x, B and C before the SSD (with
+    # its own per-slot state at decode); 0 = no conv (zamba2 here)
+    conv_width: int = 0
+    conv_bias: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                  # dense | moe | ssm | hybrid | audio | vlm
+    family: str                  # dense | moe | ssm | hybrid | layered | audio | vlm
     n_layers: int
     d_model: int
     n_heads: int
@@ -132,6 +136,14 @@ class ModelConfig:
     approx: ApproxConfig = ApproxConfig()
     # hybrid wiring (zamba2): shared attention block applied every N layers
     attn_every: int = 0          # 0 = no shared-attn interleave
+    # layered wiring (family "layered", granite-4.0-h): the mixer of each
+    # layer, "mamba" or "attention", each followed by the layer's own FFN
+    layer_types: tuple = ()
+    # granite's scalings; the neutral defaults compile to no operation
+    embedding_multiplier: float = 1.0    # embeddings x this
+    residual_multiplier: float = 1.0     # each sublayer's output x this
+    attention_multiplier: float = 0.0    # softmax scale; 0 -> hd ** -0.5
+    logits_scaling: float = 1.0          # logits / this
     # numerics / structure
     param_dtype: str = "bfloat16"
     act_dtype: str = "bfloat16"
@@ -154,6 +166,10 @@ class ModelConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def attn_scale(self) -> float:
+        return self.attention_multiplier or self.hd ** -0.5
+
+    @property
     def pdtype(self):
         return jnp.dtype(self.param_dtype)
 
@@ -166,9 +182,9 @@ class ModelConfig:
         d, hd = self.d_model, self.hd
         emb = self.vocab * d * (1 if self.tie_embeddings else 2)
         per_layer = 0
+        attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) \
+            + (self.n_heads * hd) * d
         if self.family in ("dense", "moe", "audio", "vlm"):
-            attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) \
-                + (self.n_heads * hd) * d
             if self.moe.n_experts:
                 ffn = self.moe.n_experts * (3 if self.gated_ffn else 2) * d * self.d_ff \
                     + d * self.moe.n_experts
@@ -183,6 +199,17 @@ class ModelConfig:
             mamba = d * (2 * d_in + 2 * self.ssm.d_state) + d_in * d
             ffn = (3 if self.gated_ffn else 2) * d * self.d_ff
             per_layer = mamba + ffn
+        elif self.family == "layered":  # per-layer mixer + its own FFN
+            s = self.ssm
+            d_in = s.expand * d
+            n_heads = d_in // s.head_dim
+            conv = (s.conv_width + s.conv_bias) * (d_in + 2 * s.d_state)
+            mamba = d * (2 * d_in + 2 * s.d_state + n_heads) + conv \
+                + d_in * d
+            ffn = (3 if self.gated_ffn else 2) * d * self.d_ff
+            n_attn = self.layer_types.count("attention")
+            return emb + n_attn * attn \
+                + (self.n_layers - n_attn) * mamba + self.n_layers * ffn
         return emb + self.n_layers * per_layer
 
     def active_params_count(self) -> int:

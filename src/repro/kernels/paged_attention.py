@@ -1,9 +1,12 @@
 """Pallas TPU kernel: one decode step's attention over the paged KV stack,
 read in place.
 
-The decode cache of the uniform family keeps K and V as one stack per
-model, ``(L, N, P, Kh, hd)``: ``N`` pool pages of ``P`` tokens per layer,
-and one page of one layer is a contiguous ``P * Kh * hd`` block.  This
+A paged decode cache keeps K and V as one stack per model, ``(L, N, P,
+R, W)`` over its attention layers: ``N`` pool pages of ``P`` tokens per
+layer, a token's KV heads as ``R`` rows of ``W`` features (one head a
+row, or heads narrower than 128 lanes packed to a row, as granite-4.0-h
+packs two of 64), and one page of one layer is a contiguous ``P * R *
+W`` block.  This
 kernel reads each slot's held pages of layer ``layer`` straight from that
 stack in HBM, so the step never slices a layer's pool out of the stack,
 never gathers a ``(B, max_len)`` window and never writes a pool back.
@@ -25,7 +28,10 @@ slot's length.  The softmax is online, in f32; the value contraction
 takes the probabilities in the cache's dtype with f32 accumulation.  A
 slot whose length is 0 returns zeros.  Scoring all ``Kh`` heads costs
 ``Kh`` times the scores of a per-head contraction, which is noise at
-decode sizes: the kernel is bound by the pages it reads.
+decode sizes: the kernel is bound by the pages it reads.  With packed
+rows, each query goes into its KV head's lane block of a zero row and
+the head mask picks the row holding that head, so a row's other heads
+score nothing; the output keeps the head's lane block.
 
 A slot's length must not exceed its leading run of held pages (the
 block table holds ``-1`` past it): ``held_lengths`` clips ``pos + 1`` to
@@ -143,32 +149,60 @@ def _kernel(ppb, page, n_pp, scale, layer_ref, bt_ref, len_ref, q_ref,
                          0.0).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_attention(q: jax.Array, k_stack: jax.Array, v_stack: jax.Array,
                     layer: jax.Array, block_table: jax.Array,
-                    lengths: jax.Array, *,
+                    lengths: jax.Array, *, scale: float | None = None,
                     interpret: bool = False) -> jax.Array:
     """Decode attention of layer ``layer`` over the held pages, in place.
 
-    q: (B, H, hd), one query per slot; k_stack, v_stack: (L, N, P, Kh, hd);
-    layer: int32 scalar; block_table: (B, n_pp) int32 (-1 = hole);
-    lengths: (B,) int32 from ``held_lengths``.  Returns (B, H, hd) in
-    q's dtype: softmax(q k^T / sqrt(hd)) v over each slot's first
-    ``lengths`` tokens, zeros where the length is 0.
+    q: (B, H, hd), one query per slot; k_stack, v_stack: (L, N, P, R, W),
+    a token's K/V heads as R rows of W features (``layers.pool_rows``):
+    one head a row, or W // hd heads packed in a row of W lanes; layer:
+    int32 scalar; block_table: (B, n_pp) int32 (-1 = hole); lengths:
+    (B,) int32 from ``held_lengths``.  Returns (B, H, hd) in q's dtype:
+    softmax(scale * q k^T) v over each slot's first ``lengths`` tokens,
+    zeros where the length is 0; ``scale`` defaults to ``hd ** -0.5``.
     """
+    b, h, hd = q.shape
+    _, _, page, kh, width = k_stack.shape
+    ppb = min(block_table.shape[1], pl.cdiv(BLOCK_TOKENS, page))
+    # query head i reads KV head g = i // rep, which sits in row g // pack
+    # at lane block g % pack (pack = 1: one head a row, block 0).  The
+    # query goes to that lane block of a zero row, so a row's other heads
+    # score nothing; the output keeps that block of the value rows.  A
+    # block's (T * R, W) view is token-major, row-minor.
+    assert width % hd == 0, (width, hd)
+    pack = width // hd
+    assert h % (kh * pack) == 0, (h, kh, pack)
+    rep = h // (kh * pack)
+    ids = lambda n: jnp.arange(n, dtype=jnp.int32)
+    lane = jnp.tile(jnp.repeat(ids(pack), rep), kh)         # g % pack
+    row = jnp.repeat(ids(kh), rep * pack)                   # g // pack
+    # XLA does not fold the lane block's multiply and take at pack = 1
+    qk = q
+    if pack > 1:
+        blk = jax.nn.one_hot(lane, pack, dtype=q.dtype)
+        qk = (q[:, :, None, :] * blk[None, :, :, None]).reshape(b, h, width)
+    grp = (jnp.tile(ids(kh), ppb * page)[None, :]
+           == row[:, None]).astype(jnp.int32)
+    tok = jnp.repeat(ids(ppb * page), kh)[None, :]
+    if scale is None:
+        scale = hd ** -0.5
+    out = _paged_call(qk, k_stack, v_stack, layer, block_table, lengths,
+                      grp, tok, ppb=ppb, scale=scale, interpret=interpret)
+    if pack == 1:
+        return out
+    out = out.reshape(b, h, pack, hd)
+    return jnp.take_along_axis(out, lane[None, :, None, None], axis=2)[:, :, 0]
+
+
+def _paged_call(q, k_stack, v_stack, layer, block_table, lengths, grp, tok,
+                *, ppb, scale, interpret):
     b, h, hd = q.shape
     _, _, page, kh, _ = k_stack.shape
     n_pp = block_table.shape[1]
-    assert h % kh == 0, (h, kh)
-    rep = h // kh
-    ppb = min(n_pp, pl.cdiv(BLOCK_TOKENS, page))
     rows = ppb * page * kh
-    # a block's (T * Kh, hd) view is token-major, head-minor; query head
-    # i reads KV head i // rep
-    kv_head = jnp.arange(kh, dtype=jnp.int32)
-    grp = (jnp.tile(kv_head, ppb * page)[None, :]
-           == jnp.repeat(kv_head, rep)[:, None]).astype(jnp.int32)
-    tok = jnp.repeat(jnp.arange(ppb * page, dtype=jnp.int32), kh)[None, :]
 
     # index maps take the grid index and the three prefetched operands
     def _slot(i, layer_s, bt_s, len_s):
@@ -192,7 +226,7 @@ def paged_attention(q: jax.Array, k_stack: jax.Array, v_stack: jax.Array,
         scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SMEM((1,), jnp.int32)],
     )
-    kernel = functools.partial(_kernel, ppb, page, n_pp, hd ** -0.5)
+    kernel = functools.partial(_kernel, ppb, page, n_pp, scale)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

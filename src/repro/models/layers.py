@@ -85,13 +85,19 @@ def init_embed(key, cfg: ModelConfig):
 def embed_fwd(cfg: ModelConfig, p, tokens_or_embeds: jax.Array) -> jax.Array:
     if cfg.input_mode == "embeddings":
         return tokens_or_embeds.astype(cfg.adtype)
-    return jnp.take(p["tok"], tokens_or_embeds, axis=0).astype(cfg.adtype)
+    x = jnp.take(p["tok"], tokens_or_embeds, axis=0).astype(cfg.adtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
 
 
 @jax.named_scope(scopes.UNEMBED)
 def unembed_fwd(cfg: ModelConfig, p, x: jax.Array) -> jax.Array:
     w = p["tok"].T if cfg.tie_embeddings else p["unembed"]
-    return jnp.dot(x, w.astype(x.dtype))
+    logits = jnp.dot(x, w.astype(x.dtype))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
+    return logits
 
 
 # ---------------------------------------------------------------------------
@@ -178,7 +184,7 @@ def flash_attention(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
     qb, kb = min(cfg.q_block, sq), min(cfg.kv_block, skv)
     nq, nk = sq // qb, skv // kb
     assert sq % qb == 0 and skv % kb == 0, (sq, qb, skv, kb)
-    scale = hd ** -0.5
+    scale = cfg.attn_scale
     q = q.reshape(b, nq, qb, h, hd)
     k = k.reshape(b, nk, kb, h, hd)
     v = v.reshape(b, nk, kb, h, hd)
@@ -223,6 +229,33 @@ def flash_attention(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
                         (q.swapaxes(0, 1), jnp.arange(nq)))
     # o: (nq, b, qb, h, hd) -> (b, sq, h, hd)
     return o.swapaxes(0, 1).reshape(b, sq, h, hd)
+
+
+LANES = 128
+
+
+def pool_rows(cfg: ModelConfig) -> tuple[int, int]:
+    """(rows, width) of one token in a paged K/V pool.  Heads narrower
+    than the TPU's 128 lanes are packed, 128 // hd of them to a row, so
+    that the paged kernel copies whole aligned rows: granite-4.0-h's 8
+    KV heads of 64 are 4 rows of 128.  Other heads keep one row each."""
+    kh, hd = cfg.n_kv_heads, cfg.hd
+    if hd % LANES and LANES % hd == 0 and (kh * hd) % LANES == 0:
+        return kh * hd // LANES, LANES
+    return kh, hd
+
+
+def _to_pool(x: jax.Array, pool: jax.Array) -> jax.Array:
+    """K/V heads (..., Kh, hd) as rows of ``pool``, in its dtype."""
+    x = x.astype(pool.dtype)
+    rows = pool.shape[-2:]
+    return x if x.shape[-2:] == rows else x.reshape(*x.shape[:-2], *rows)
+
+
+def _from_pool(x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Rows read from a pool, as K/V heads (..., Kh, hd)."""
+    heads = (cfg.n_kv_heads, cfg.hd)
+    return x if x.shape[-2:] == heads else x.reshape(*x.shape[:-2], *heads)
 
 
 @jax.named_scope(scopes.KV_GATHER)
@@ -278,10 +311,11 @@ def _attention_chunk(cfg: ModelConfig, q, k, v, cache):
         pg = jnp.where(tok_ok & (pg_idx < n_pp) & (pg >= 0), pg, n_pages)
         with jax.named_scope(scopes.KV_WRITE):
             ck = cache["k"].at[pg, within].set(
-                k.astype(cache["k"].dtype), mode="drop")
+                _to_pool(k, cache["k"]), mode="drop")
             cv = cache["v"].at[pg, within].set(
-                v.astype(cache["v"].dtype), mode="drop")
-        ak, av = _gather_pages(ck, bt), _gather_pages(cv, bt)
+                _to_pool(v, cache["v"]), mode="drop")
+        ak = _from_pool(_gather_pages(ck, bt), cfg)
+        av = _from_pool(_gather_pages(cv, bt), cfg)
         new_cache = {"k": ck, "v": cv, "block_table": bt, "pos": pos + nv}
     else:
         skv = cache["k"].shape[1]
@@ -296,7 +330,7 @@ def _attention_chunk(cfg: ModelConfig, q, k, v, cache):
     rep = cfg.n_heads // cfg.n_kv_heads
     qg = q.reshape(b, sq, cfg.n_kv_heads, rep, cfg.hd)
     s_ = jnp.einsum("bqgrd,bkgd->bgrqk", qg, ak).astype(jnp.float32) \
-        * cfg.hd ** -0.5
+        * cfg.attn_scale
     s_ = jnp.where(valid[:, None, None, :, :], s_, -1e30)
     w = jax.nn.softmax(s_, axis=-1).astype(av.dtype)
     o = jnp.einsum("bgrqk,bkgd->bqgrd", w, av)
@@ -314,11 +348,12 @@ def _attention_paged_in_place(cfg: ModelConfig, p, q, k, v, cache, pg, off):
     b, i = q.shape[0], cache["layer"]
     with jax.named_scope(scopes.KV_WRITE):
         ck = cache["k"].at[i, pg, off].set(
-            k[:, 0].astype(cache["k"].dtype), mode="drop")
+            _to_pool(k[:, 0], cache["k"]), mode="drop")
         cv = cache["v"].at[i, pg, off].set(
-            v[:, 0].astype(cache["v"].dtype), mode="drop")
+            _to_pool(v[:, 0], cache["v"]), mode="drop")
     o = paged_attention(q[:, 0], ck, cv, i, cache["block_table"],
-                        cache["lengths"], interpret=cfg.approx.interpret)
+                        cache["lengths"], scale=cfg.attn_scale,
+                        interpret=cfg.approx.interpret)
     o = o.reshape(b, 1, cfg.n_heads * cfg.hd)
     return jnp.dot(o, p["wo"].astype(o.dtype)), dict(cache, k=ck, v=cv,
                                                      pos=cache["pos"] + 1)
@@ -393,10 +428,11 @@ def attention_fwd(cfg: ModelConfig, p, x: jax.Array, positions: jax.Array,
                                                  off)
             with jax.named_scope(scopes.KV_WRITE):
                 ck = cache["k"].at[pg, off].set(
-                    k[:, 0].astype(cache["k"].dtype), mode="drop")
+                    _to_pool(k[:, 0], cache["k"]), mode="drop")
                 cv = cache["v"].at[pg, off].set(
-                    v[:, 0].astype(cache["v"].dtype), mode="drop")
-            ak, av = _gather_pages(ck, bt), _gather_pages(cv, bt)
+                    _to_pool(v[:, 0], cache["v"]), mode="drop")
+            ak = _from_pool(_gather_pages(ck, bt), cfg)
+            av = _from_pool(_gather_pages(cv, bt), cfg)
             valid = jnp.arange(skv)[None, :] <= pos[:, None]
             new_cache = {"k": ck, "v": cv, "block_table": bt, "pos": pos + 1}
         else:
@@ -424,7 +460,7 @@ def attention_fwd(cfg: ModelConfig, p, x: jax.Array, positions: jax.Array,
         rep = cfg.n_heads // cfg.n_kv_heads
         qg = q.reshape(b, q.shape[1], cfg.n_kv_heads, rep, cfg.hd)
         s_ = jnp.einsum("bqgrd,bkgd->bgrqk", qg, ak).astype(jnp.float32) \
-            * cfg.hd ** -0.5
+            * cfg.attn_scale
         s_ = jnp.where(valid[:, None, None, None, :], s_, -1e30)
         w = jax.nn.softmax(s_, axis=-1).astype(av.dtype)
         o = jnp.einsum("bgrqk,bkgd->bqgrd", w, av)
@@ -440,7 +476,8 @@ def init_attn_cache(cfg: ModelConfig, batch: int, max_len: int, *,
     size.  ``pos`` is per-slot (continuous batching).
 
     ``page_size > 0`` builds the PAGED layout instead: k/v become a fixed
-    pool of ``(n_pages, page_size, Kh, hd)`` blocks shared by every slot,
+    pool of ``(n_pages, page_size, *pool_rows(cfg))`` blocks shared by
+    every slot (Kh rows of hd, or heads narrower than 128 lanes packed),
     plus a ``block_table`` (batch, max_len // page_size) int32 mapping
     each slot's page index to a pool page (-1 = unallocated — writes to a
     hole are dropped, never clamped).  ``page_size`` must divide
@@ -453,7 +490,7 @@ def init_attn_cache(cfg: ModelConfig, batch: int, max_len: int, *,
             f"page_size={page_size} must divide max_len={max_len}")
         assert n_pages >= 1, f"paged cache needs n_pages >= 1, got {n_pages}"
         n_pp = max_len // page_size
-        shape = (n_pages, page_size, cfg.n_kv_heads, cfg.hd)
+        shape = (n_pages, page_size, *pool_rows(cfg))
         return {"k": jnp.zeros(shape, cfg.adtype),
                 "v": jnp.zeros(shape, cfg.adtype),
                 "block_table": jnp.full((batch, n_pp), -1, jnp.int32),

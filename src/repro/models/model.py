@@ -8,6 +8,11 @@ Families and their block layouts:
   hybrid (zamba2)     : G x (k mamba2 + SHARED attn/FFN block)  (k =
                         attn_every; the shared block's params are one set
                         applied at every group boundary — Zamba2's design)
+  layered (granite-4.0-h): N x (mixer + FFN), each layer's mixer a Mamba2
+                        or an attention as ``cfg.layer_types`` lists it,
+                        each layer with its own FFN  [scan over periods of
+                        the pattern, an inner scan over each run of one
+                        mixer kind]
 
 All stacking uses lax.scan over stacked param pytrees so compile time is
 O(1) in depth; ``cfg.remat`` wraps block bodies in jax.checkpoint.
@@ -78,12 +83,19 @@ def _dense_block(cfg: ModelConfig, p, x, positions, cache, *, serve=False,
         f, aux, metrics = f
         x = x + h + f
     else:
-        x = x + h
+        x = _residual(cfg, x, h)
         f, aux, metrics = _ffn_part(cfg, p, L.norm_fwd(cfg, p["ln2"], x),
                                     serve, row_mask, dispatch_plan, tier,
                                     tier_margins, residency)
-        x = x + f
+        x = _residual(cfg, x, f)
     return x, new_cache, aux, metrics
+
+
+def _residual(cfg: ModelConfig, x, y):
+    """x + a sublayer's output, scaled by ``cfg.residual_multiplier``."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return x + y
 
 
 def _ffn_part(cfg: ModelConfig, p, xn, serve, row_mask=None,
@@ -161,6 +173,123 @@ def _mamba_block(cfg, p, x, state):
     return x + y, st
 
 
+# ---- layered (granite-4.0-h) -----------------------------------------------
+
+MAMBA, ATTENTION = "mamba", "attention"
+MIXERS = (MAMBA, ATTENTION)
+
+
+def _init_mixer(key, cfg, kind):
+    core = (mamba2.init_mamba(key, cfg) if kind == MAMBA
+            else L.init_attn(key, cfg))
+    return {"ln": L.init_norm(cfg, cfg.d_model),
+            ("core" if kind == MAMBA else "attn"): core}
+
+
+def _init_layer_ffn(key, cfg):
+    p = {"ln": L.init_norm(cfg, cfg.d_model)}
+    if cfg.approx.enable:
+        p["approx"] = init_approx_ffn(key, cfg)
+    else:
+        p["ffn"] = L.init_ffn(key, cfg)
+    return p
+
+
+@dataclasses.dataclass(frozen=True)
+class Run:
+    """Consecutive layers of one mixer kind inside a period of the
+    layered pattern."""
+
+    kind: str
+    start: int           # first layer, within the period
+    length: int
+    first: int           # its index among the period's layers of its kind
+
+
+def layer_runs(cfg: ModelConfig) -> tuple[int, tuple[Run, ...]]:
+    """The shortest period that tiles ``cfg.layer_types``, and its runs.
+    granite-4.0-h-micro's 40 layers are 4 periods of 10: five Mamba2
+    layers, one attention layer, four Mamba2 layers."""
+    types = tuple(cfg.layer_types)
+    n = len(types)
+    assert n == cfg.n_layers and set(types) <= set(MIXERS), (
+        f"layer_types must give one of {MIXERS} per layer "
+        f"(n_layers={cfg.n_layers}, got {types})")
+    period = next(p for p in range(1, n + 1)
+                  if n % p == 0 and types == types[:p] * (n // p))
+    runs, seen = [], {k: 0 for k in MIXERS}
+    for j, kind in enumerate(types[:period]):
+        if runs and runs[-1].kind == kind:
+            runs[-1] = dataclasses.replace(runs[-1],
+                                           length=runs[-1].length + 1)
+        else:
+            runs.append(Run(kind, j, 1, seen[kind]))
+        seen[kind] += 1
+    return period, tuple(runs)
+
+
+def _run_layered(cfg: ModelConfig, params, x, carry, layer, *,
+                 remat: bool = False):
+    """Every layer of the layered stack in order, as one scan over the
+    periods of the pattern with an inner scan over each run of one mixer
+    kind: one traced body per run of a period, whatever the depth.
+
+    ``layer(kind, mix, ffn, i, x, carry) -> (x, carry, out)`` applies one
+    layer: ``mix`` and ``ffn`` are its parameters, ``i`` (traced) its
+    index among the layers of its kind (the row of that kind's stack in
+    the parameters and the cache).  Returns ``(x, carry, outs)`` with
+    each leaf of ``out`` stacked over the layers.  ``remat`` wraps each
+    layer in jax.checkpoint (the training forward)."""
+    period, runs = layer_runs(cfg)
+    per = {k: cfg.layer_types[:period].count(k) for k in MIXERS}
+    fn = jax.checkpoint if remat else (lambda f: f)
+
+    def group(xc, g):
+        outs = []
+        for r in runs:
+            def one(xc, j, r=r):
+                x, carry = xc
+                i = g * per[r.kind] + r.first + j
+                mix = jax.tree.map(lambda a: a[i], params[r.kind])
+                ffn = jax.tree.map(lambda a: a[g * period + r.start + j],
+                                   params["ffn"])
+                x, carry, out = layer(r.kind, mix, ffn, i, x, carry)
+                return (x, carry), out
+            if r.length == 1:
+                xc, out = fn(one)(xc, 0)
+                out = jax.tree.map(lambda a: a[None], out)
+            else:
+                xc, out = jax.lax.scan(fn(one), xc, jnp.arange(r.length))
+            outs.append(out)
+        return xc, jax.tree.map(lambda *a: jnp.concatenate(a), *outs)
+    (x, carry), outs = jax.lax.scan(group, (x, carry),
+                                    jnp.arange(cfg.n_layers // period))
+    return x, carry, jax.tree.map(
+        lambda a: a.reshape(-1, *a.shape[2:]), outs)
+
+
+def _layered_ffn(cfg: ModelConfig, p, x, **kw):
+    """The layer's FFN sublayer: (x, aux, metrics)."""
+    f, aux, m = _ffn_part(cfg, p, L.norm_fwd(cfg, p["ln"], x), **kw)
+    return _residual(cfg, x, f), aux, m
+
+
+def _mamba_mixer(cfg: ModelConfig, mix, x, states, i, tok_mask):
+    """The Mamba2 sublayer of layer ``i`` of the mixer stack against the
+    cache's state stacks ``states`` ((n_mamba, B, ...) leaves)."""
+    with jax.named_scope(scopes.SSM_MIXER), \
+            jax.named_scope(scopes.SSM_STATE):
+        st = jax.tree.map(lambda a: a[i], states)
+    y, st = mamba2.mamba_fwd(cfg, mix["core"], L.norm_fwd(cfg, mix["ln"], x),
+                             st, tok_mask=tok_mask)
+    with jax.named_scope(scopes.SSM_MIXER), \
+            jax.named_scope(scopes.SSM_STATE):
+        states = jax.tree.map(
+            lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                a, n.astype(a.dtype), i, 0), states, st)
+    return _residual(cfg, x, y), states
+
+
 # ---------------------------------------------------------------------------
 # Model topology descriptors
 # ---------------------------------------------------------------------------
@@ -169,12 +298,18 @@ def _mamba_block(cfg, p, x, state):
 class Topology:
     """How layers group into scan stacks for a family."""
 
-    kind: str            # "uniform" | "xlstm" | "hybrid"
+    kind: str            # "uniform" | "xlstm" | "hybrid" | "layered"
     n_groups: int = 0
-    per_group: int = 0   # inner homogeneous run length
+    per_group: int = 0   # inner homogeneous run length (layered: the
+                         # pattern's period)
 
 
 def topology(cfg: ModelConfig) -> Topology:
+    if cfg.family == "layered":
+        assert not cfg.moe.n_experts and not cfg.sliding_window, \
+            "the layered family has dense FFNs and full attention"
+        period, _ = layer_runs(cfg)
+        return Topology("layered", cfg.n_layers // period, period)
     if cfg.family == "ssm":
         k = cfg.ssm.slstm_every
         assert cfg.n_layers % k == 0, (cfg.n_layers, k)
@@ -209,6 +344,19 @@ def init_model(key: jax.Array, cfg: ModelConfig):
             params["mlstm"])
         params["slstm"] = _stack_init(ksl, topo.n_groups,
                                       lambda k: _init_slstm_block(k, cfg))
+    elif topo.kind == "layered":
+        # layer i draws its mixer and its FFN from the two halves of its
+        # own key; each kind's mixers stack in layer order
+        keys = jax.vmap(jax.random.split)(
+            jax.random.split(kb, cfg.n_layers))
+        for kind in MIXERS:
+            idx = [i for i, t in enumerate(cfg.layer_types) if t == kind]
+            if idx:
+                params[kind] = jax.vmap(
+                    lambda k, kind=kind: _init_mixer(k, cfg, kind))(
+                        keys[jnp.asarray(idx), 0])
+        params["ffn"] = jax.vmap(lambda k: _init_layer_ffn(k, cfg))(
+            keys[:, 1])
     else:  # hybrid
         km, ka = jax.random.split(kb)
         params["mamba"] = _stack_init(
@@ -221,7 +369,7 @@ def init_model(key: jax.Array, cfg: ModelConfig):
         params["shared"] = _init_dense_block(ks, dataclasses.replace(
             cfg, moe=dataclasses.replace(cfg.moe, n_experts=0)))
     if cfg.approx.enable and not cfg.moe.n_experts \
-            and topo.kind in ("uniform", "hybrid"):
+            and topo.kind in ("uniform", "hybrid", "layered"):
         # tick-router head (route_scope="tick"): ONE (d, n+1) classifier on
         # the pre-layer hidden state, co-trained on the competitive labels
         # aggregated across layers — the paper's one decision per input,
@@ -302,7 +450,7 @@ def forward(cfg: ModelConfig, params, inputs: jax.Array, *,
             cache = {"mlstm": mstates, "slstm": sstates,
                      "pos": jnp.full((b,), s, jnp.int32)}
 
-    else:  # hybrid
+    elif topo.kind == "hybrid":
         shared = params["shared"]
 
         def group(carry, mblks):
@@ -330,7 +478,33 @@ def forward(cfg: ModelConfig, params, inputs: jax.Array, *,
             cache = {"mamba": mstates, "k": ks, "v": vs,
                      "pos": jnp.full((b,), s, jnp.int32)}
 
-    if train_tick and topo.kind in ("uniform", "hybrid"):
+    elif topo.kind == "layered":
+        assert not collect_cache, \
+            "the layered family prefills through decode_chunk"
+        plan = None
+        if serve and cfg.approx.enable and cfg.approx.route_scope == "tick":
+            from repro.models.approx_ffn import make_tick_plan
+            plan = make_tick_plan(cfg, params, x)
+
+        def layer(kind, mix, ffn, i, x, votes):
+            if kind == MAMBA:
+                y, _ = mamba2.mamba_fwd(cfg, mix["core"],
+                                        L.norm_fwd(cfg, mix["ln"], x))
+            else:
+                y, _ = L.attention_fwd(cfg, mix["attn"],
+                                       L.norm_fwd(cfg, mix["ln"], x),
+                                       positions)
+            x, aux, m = _layered_ffn(cfg, ffn, _residual(cfg, x, y),
+                                     serve=serve, dispatch_plan=plan)
+            if "_label_votes" in m:
+                votes = votes + m.pop("_label_votes")
+            return constrain(x), votes, (aux, m)
+        x, votes, (auxs, ms) = _run_layered(cfg, params, x, votes0, layer,
+                                            remat=cfg.remat)
+        aux_total = jnp.sum(auxs)
+        metrics = {k: jnp.mean(v, axis=0) for k, v in ms.items()}
+
+    if train_tick and topo.kind in ("uniform", "hybrid", "layered"):
         tick_labels = jnp.argmax(votes, -1)
         t_logits = jnp.dot(x0.reshape(b * s, -1),
                            params["tick_router"].astype(x0.dtype)) \
@@ -357,17 +531,35 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, *,
                page_size: int = 0, kv_pages: int = 0):
     """Empty decode cache sized for ``max_len`` context.
 
-    ``page_size > 0`` builds the PAGED layout (uniform family only): k/v
-    are per-layer pools ``(L, kv_pages, page_size, Kh, hd)`` plus ONE
-    ``block_table`` (batch, max_len // page_size) shared by every layer —
-    all layers write the same positions, so one table serves the stack.
-    ``page_size=0`` keeps the dense ``(L, batch, max_len, Kh, hd)``
-    layout, the bit-exact oracle."""
+    ``page_size > 0`` builds the PAGED layout (uniform and layered
+    families): k/v are per-layer pools ``(L, kv_pages, page_size,
+    *layers.pool_rows(cfg))`` (Kh rows of hd; narrow heads packed) plus
+    ONE ``block_table`` (batch, max_len // page_size) shared by every
+    layer — all layers write the same positions, so one table
+    serves the stack.  ``page_size=0`` keeps the dense ``(L, batch,
+    max_len, Kh, hd)`` layout, the bit-exact oracle.
+
+    The layered family's k/v stacks hold its attention layers alone, and
+    beside them ``mamba`` holds each Mamba2 layer's recurrent and conv
+    state, ``(n_mamba, batch, ...)`` leaves: one slot's state is a
+    fixed size, so it is not paged."""
     topo = topology(cfg)
+    if topo.kind == "layered":
+        n = {k: cfg.layer_types.count(k) for k in MIXERS}
+        stack = lambda a, k: jnp.broadcast_to(a, (n[k], *a.shape))
+        c = {"mamba": jax.tree.map(lambda a: stack(a, MAMBA),
+                                   mamba2.init_mamba_state(cfg, batch))}
+        ac = L.init_attn_cache(cfg, batch, max_len, page_size=page_size,
+                               n_pages=kv_pages)
+        c["k"], c["v"] = stack(ac["k"], ATTENTION), stack(ac["v"], ATTENTION)
+        if page_size:
+            c["block_table"] = ac["block_table"]
+        c["pos"] = jnp.zeros((batch,), jnp.int32)
+        return c
     if page_size:
         assert topo.kind == "uniform" and not cfg.sliding_window, (
-            "paged KV caches need the uniform dense-attention family "
-            f"(got family={cfg.family!r}, "
+            "paged KV caches need the uniform or layered family with "
+            f"full attention (got family={cfg.family!r}, "
             f"sliding_window={cfg.sliding_window})")
         c = L.init_attn_cache(cfg, batch, max_len, page_size=page_size,
                               n_pages=kv_pages)
@@ -398,24 +590,36 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, *,
             "pos": jnp.zeros((batch,), jnp.int32)}
 
 
-def reset_slot(cfg: ModelConfig, cache, fresh, slot: int):
-    """Reset one batch slot of a decode cache to ``fresh`` (a cache from
-    init_cache): continuous batching admits a new request into a freed
-    slot.  Batch-dim position per leaf: k/v (L, B, ...) -> 1; mlstm/mamba
-    states (G, P, B, ...) -> 2; slstm states (G, B, ...) -> 1; pos -> 0.
+def reset_slot(cfg: ModelConfig, cache, slot):
+    """Reset one batch slot of a decode cache to what ``init_cache``
+    makes: continuous batching admits a new request into a freed slot.
+    ``slot`` may be traced, so one compiled reset serves every slot.
+    Batch-dim position per leaf: k/v (L, B, ...) -> 1; hybrid mamba and
+    mlstm states (G, P, B, ...) -> 2; layered mamba states (n_mamba, B,
+    ...) and slstm states (G, B, ...) -> 1; pos, block_table -> 0.  The
+    slot's values come from a one-slot ``init_cache``, constants under
+    jit, so the init functions stay the one source of initial values.
 
     Paged caches (``block_table`` present): the k/v pools are SHARED by
     every slot — freeing pages is the server allocator's job, so the
     pools pass through untouched and only the slot's block-table row
-    (back to -1) and ``pos`` (back to 0) reset."""
-    paged = isinstance(cache, dict) and "block_table" in cache
+    (back to -1), its ``pos`` (back to 0) and any recurrent state reset."""
+    paged = "block_table" in cache
+    layered = topology(cfg).kind == "layered"
+    page = cache["k"].shape[2] if paged else 0
+    if paged:
+        max_len = cache["block_table"].shape[1] * page
+    else:
+        max_len = cache["k"].shape[2] if "k" in cache else 1
+    fresh = init_cache(cfg, 1, max_len, page_size=page,
+                       kv_pages=1 if paged else 0)
 
     def bdim(path):
         head = path[0]
         if head in ("k", "v"):
             return None if paged else 1
         if head in ("mlstm", "mamba"):
-            return 2
+            return 1 if layered else 2
         if head == "slstm":
             return 1
         return 0  # pos, block_table
@@ -427,7 +631,7 @@ def reset_slot(cfg: ModelConfig, cache, fresh, slot: int):
         if d is None:
             return c                       # shared page pool: not per-slot
         idx = tuple([slice(None)] * d + [slot])
-        return c.at[idx].set(f[idx])
+        return c.at[idx].set(jnp.take(f, 0, axis=d))
     return walk((), cache, fresh)
 
 
@@ -457,6 +661,43 @@ def _pages_read_in_place(cfg: ModelConfig) -> bool:
             and manual_dp_context()[0] is None)
 
 
+def _decode_kv_plan(cfg: ModelConfig, cache, row_mask):
+    """(block table or None, whether the step reads its pages in place,
+    tokens each slot attends there — a masked slot fetches nothing)."""
+    bt = cache.get("block_table")         # paged: ONE table for all layers
+    in_place = bt is not None and _pages_read_in_place(cfg)
+    lengths = None
+    if in_place:
+        lengths = held_lengths(cache["pos"], bt, cache["k"].shape[2])
+        if row_mask is not None:
+            lengths = jnp.where(row_mask, lengths, 0)
+    return bt, in_place, lengths
+
+
+def _layer_kv(ck, cv, i, pos, bt, in_place, lengths):
+    """The attention cache of layer ``i`` of the k/v stacks for a decode
+    step: the stacks themselves when the paged kernel reads them in
+    place, else the layer's pool (or dense window) sliced out."""
+    if in_place:
+        return {"k": ck, "v": cv, "pos": pos, "block_table": bt,
+                "layer": i, "lengths": lengths}
+    with jax.named_scope(scopes.KV_GATHER):
+        lc = {"k": ck[i], "v": cv[i], "pos": pos}
+    if bt is not None:
+        lc["block_table"] = bt
+    return lc
+
+
+def _store_kv(ck, cv, nc, i, in_place):
+    """The k/v stacks after layer ``i`` wrote its cache ``nc``."""
+    if in_place:
+        return nc["k"], nc["v"]
+    with jax.named_scope(scopes.KV_WRITE):
+        ck = jax.lax.dynamic_update_index_in_dim(ck, nc["k"], i, 0)
+        cv = jax.lax.dynamic_update_index_in_dim(cv, nc["v"], i, 0)
+    return ck, cv
+
+
 def decode(cfg: ModelConfig, params, cache, inputs: jax.Array, *,
            serve: bool = True, collect_metrics: bool = False,
            row_mask: jax.Array | None = None,
@@ -466,7 +707,7 @@ def decode(cfg: ModelConfig, params, cache, inputs: jax.Array, *,
     """One decode step.  inputs: tokens (B, 1) or embeds (B, 1, d).
     Returns (logits (B, V), new_cache), or (logits, new_cache, metrics)
     when ``collect_metrics`` — layer-meaned per-step block metrics (e.g.
-    the ApproxFFN dispatch invocation rate; uniform family only).
+    the ApproxFFN dispatch invocation rate).
 
     ``row_mask`` (optional, (B,) bool) marks the ACTIVE batch slots of a
     continuous-batching server.  Idle slots (fed dummy token 0) are
@@ -513,7 +754,7 @@ def decode(cfg: ModelConfig, params, cache, inputs: jax.Array, *,
                              f"{cfg.approx.route_scope!r} "
                              "(expected 'layer' or 'tick')")
         if (cfg.approx.route_scope == "tick" and not cfg.moe.n_experts
-                and topo.kind in ("uniform", "hybrid")):
+                and topo.kind in ("uniform", "hybrid", "layered")):
             from repro.models.approx_ffn import make_tick_plan
             plan = make_tick_plan(cfg, params, x, row_mask, tier=tier,
                                   tier_margins=tier_margins,
@@ -525,36 +766,18 @@ def decode(cfg: ModelConfig, params, cache, inputs: jax.Array, *,
         # inside the while loop aliases the donated input buffer) — passing
         # it as scan xs/ys would materialize two extra (L, B, S, KV, hd)
         # temporaries, which at 32k context is the whole HBM budget.
-        bt = cache.get("block_table")     # paged: ONE table for all layers
-        in_place = bt is not None and _pages_read_in_place(cfg)
-        if in_place:
-            # tokens each slot attends; a masked slot fetches nothing
-            lengths = held_lengths(pos, bt, cache["k"].shape[2])
-            if row_mask is not None:
-                lengths = jnp.where(row_mask, lengths, 0)
+        bt, in_place, lengths = _decode_kv_plan(cfg, cache, row_mask)
 
         def body(carry, blk_i):
             x, ck, cv = carry
             blk, i = blk_i
-            if in_place:
-                lc = {"k": ck, "v": cv, "pos": pos, "block_table": bt,
-                      "layer": i, "lengths": lengths}
-            else:
-                with jax.named_scope(scopes.KV_GATHER):
-                    lc = {"k": ck[i], "v": cv[i], "pos": pos}
-                if bt is not None:
-                    lc["block_table"] = bt
+            lc = _layer_kv(ck, cv, i, pos, bt, in_place, lengths)
             x, nc, _, m = _dense_block(cfg, blk, x, positions, lc, serve=serve,
                                        row_mask=row_mask, dispatch_plan=plan,
                                        tier=tier, tier_margins=tier_margins,
                                        residency=residency)
             m.pop("_label_votes", None)   # train-only co-training signal
-            if in_place:
-                ck, cv = nc["k"], nc["v"]
-            else:
-                with jax.named_scope(scopes.KV_WRITE):
-                    ck = jax.lax.dynamic_update_index_in_dim(ck, nc["k"], i, 0)
-                    cv = jax.lax.dynamic_update_index_in_dim(cv, nc["v"], i, 0)
+            ck, cv = _store_kv(ck, cv, nc, i, in_place)
             return (x, ck, cv), (m if collect_metrics else None)
         (x, ks, vs), ms = jax.lax.scan(
             body, (x, cache["k"], cache["v"]),
@@ -586,6 +809,38 @@ def decode(cfg: ModelConfig, params, cache, inputs: jax.Array, *,
             group, x, (params["mlstm"], cache["mlstm"], params["slstm"],
                        cache["slstm"]))
         new_cache = {"mlstm": nm, "slstm": nsl, "pos": pos + 1}
+
+    elif topo.kind == "layered":
+        # the step's masked rows keep their recurrent state, as their pos
+        bt, in_place, lengths = _decode_kv_plan(cfg, cache, row_mask)
+        tok_mask = None if row_mask is None \
+            else row_mask.astype(bool)[:, None]
+
+        def layer(kind, mix, ffn, i, x, carry):
+            states, ck, cv = carry
+            if kind == MAMBA:
+                x, states = _mamba_mixer(cfg, mix, x, states, i, tok_mask)
+            else:
+                lc = _layer_kv(ck, cv, i, pos, bt, in_place, lengths)
+                y, nc = L.attention_fwd(cfg, mix["attn"],
+                                        L.norm_fwd(cfg, mix["ln"], x),
+                                        positions, lc)
+                ck, cv = _store_kv(ck, cv, nc, i, in_place)
+                x = _residual(cfg, x, y)
+            x, _, m = _layered_ffn(cfg, ffn, x, serve=serve,
+                                   row_mask=row_mask, dispatch_plan=plan,
+                                   tier=tier, tier_margins=tier_margins,
+                                   residency=residency)
+            m.pop("_label_votes", None)   # train-only co-training signal
+            return x, (states, ck, cv), (m if collect_metrics else None)
+        x, (states, ks, vs), ms = _run_layered(
+            cfg, params, x, (cache["mamba"], cache["k"], cache["v"]), layer)
+        adv = 1 if row_mask is None else row_mask.astype(jnp.int32)
+        new_cache = {"mamba": states, "k": ks, "v": vs, "pos": pos + adv}
+        if bt is not None:
+            new_cache["block_table"] = bt
+        if collect_metrics and ms is not None:
+            step_metrics = {k: jnp.mean(v, axis=0) for k, v in ms.items()}
 
     else:  # hybrid
         shared = params["shared"]
@@ -648,18 +903,21 @@ def decode_chunk(cfg: ModelConfig, params, cache, tokens: jax.Array,
     decode step (bit-identical to token-by-token serving), so the unembed
     matmul over all S positions is skipped entirely.
 
-    Uniform (dense-attention) family with a dense KV cache only: SSM and
-    hybrid recurrences, and sliding-window ring buffers, consume their
+    Uniform and layered families with full attention: the xLSTM and
+    zamba2 recurrences, and sliding-window ring buffers, consume their
     prompts token-by-token (the server's scheduler falls back for them).
+    A layered model's Mamba2 layers run the chunked SSD from each slot's
+    cached state, over the slot's valid tokens only.
     The serve-mode FFN dispatch (and the tick-scope plan) runs on the
     B*S chunk rows under a TOKEN-level mask — per-slot activity AND the
     per-token n_valid bound — so padded rows never touch the router,
     the capacity dispatch, or any invoke stat.
     """
     topo = topology(cfg)
-    assert topo.kind == "uniform" and not cfg.sliding_window, \
-        "decode_chunk needs the uniform family with a dense KV cache " \
-        f"(got family={cfg.family!r}, sliding_window={cfg.sliding_window})"
+    assert topo.kind in ("uniform", "layered") and not cfg.sliding_window, \
+        "decode_chunk needs the uniform or layered family with full " \
+        f"attention (got family={cfg.family!r}, " \
+        f"sliding_window={cfg.sliding_window})"
     b, s = tokens.shape[0], tokens.shape[1]
     x = L.embed_fwd(cfg, params["embed"], tokens)
     pos = cache["pos"]                                   # (B,) per-slot
@@ -678,26 +936,53 @@ def decode_chunk(cfg: ModelConfig, params, cache, tokens: jax.Array,
 
     bt = cache.get("block_table")         # paged: ONE table for all layers
 
-    def body(carry, blk_i):
-        x, ck, cv = carry
-        blk, i = blk_i
+    def chunk_kv(ck, cv, i):
         with jax.named_scope(scopes.KV_GATHER):
             lc = {"k": ck[i], "v": cv[i], "pos": pos, "n_valid": n_valid}
         if bt is not None:
             lc["block_table"] = bt
-        x, nc, _, m = _dense_block(cfg, blk, x, positions, lc, serve=serve,
+        return lc
+
+    if topo.kind == "layered":
+        # each Mamba2 layer starts from its slot's state, and the tokens
+        # past a slot's n_valid (or of a masked slot) leave it as it was
+        def layer(kind, mix, ffn, i, x, carry):
+            states, ck, cv = carry
+            if kind == MAMBA:
+                x, states = _mamba_mixer(cfg, mix, x, states, i, tok_mask)
+            else:
+                y, nc = L.attention_fwd(cfg, mix["attn"],
+                                        L.norm_fwd(cfg, mix["ln"], x),
+                                        positions, chunk_kv(ck, cv, i))
+                ck, cv = _store_kv(ck, cv, nc, i, False)
+                x = _residual(cfg, x, y)
+            x, _, m = _layered_ffn(cfg, ffn, x, serve=serve,
                                    row_mask=tok_mask, dispatch_plan=plan,
                                    tier=tier, tier_margins=tier_margins,
                                    residency=residency)
-        m.pop("_label_votes", None)   # train-only co-training signal
-        with jax.named_scope(scopes.KV_WRITE):
-            ck = jax.lax.dynamic_update_index_in_dim(ck, nc["k"], i, 0)
-            cv = jax.lax.dynamic_update_index_in_dim(cv, nc["v"], i, 0)
-        return (x, ck, cv), (m if collect_metrics else None)
-    (_, ks, vs), ms = jax.lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (params["blocks"], jnp.arange(cfg.n_layers)))
-    new_cache = {"k": ks, "v": vs, "pos": pos + n_valid.astype(jnp.int32)}
+            m.pop("_label_votes", None)   # train-only co-training signal
+            return x, (states, ck, cv), (m if collect_metrics else None)
+        _, (states, ks, vs), ms = _run_layered(
+            cfg, params, x, (cache["mamba"], cache["k"], cache["v"]), layer)
+        new_cache = {"mamba": states}
+    else:
+        def body(carry, blk_i):
+            x, ck, cv = carry
+            blk, i = blk_i
+            lc = chunk_kv(ck, cv, i)
+            x, nc, _, m = _dense_block(cfg, blk, x, positions, lc,
+                                       serve=serve, row_mask=tok_mask,
+                                       dispatch_plan=plan, tier=tier,
+                                       tier_margins=tier_margins,
+                                       residency=residency)
+            m.pop("_label_votes", None)   # train-only co-training signal
+            ck, cv = _store_kv(ck, cv, nc, i, False)
+            return (x, ck, cv), (m if collect_metrics else None)
+        (_, ks, vs), ms = jax.lax.scan(
+            body, (x, cache["k"], cache["v"]),
+            (params["blocks"], jnp.arange(cfg.n_layers)))
+        new_cache = {}
+    new_cache.update(k=ks, v=vs, pos=pos + n_valid.astype(jnp.int32))
     if bt is not None:
         new_cache["block_table"] = bt
     metrics: dict[str, jax.Array] = {}

@@ -14,8 +14,10 @@ token goes through the decode step — so the first sampled token, and every
 decode-phase computation after it, is bit-identical to token-by-token
 serving (``prefill_chunk=0``, the pre-chunking reference mode, still
 available and used by the bench as the TTFT baseline).  Chunking needs the
-uniform (dense-attention) family with a dense KV cache; SSM/hybrid/
+uniform or the layered family with full attention; xLSTM/zamba2/
 sliding-window models fall back to token-by-token feeding automatically.
+A layered model's Mamba2 layers keep a fixed-size recurrent state per
+slot beside the KV cache; a slot's reset zeroes it.
 
 The ``max_len`` contract: positions are absolute, never recycled.
 ``submit()`` enforces ``len(prompt) + max_new <= max_len`` loudly (or
@@ -41,6 +43,7 @@ path at scale IS the lowered cell, just driven by this loop.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 import warnings
 from typing import Callable, Optional
@@ -329,11 +332,13 @@ class DecodeServer:
         # prefill_chunk: S > 0 turns on chunked prefill — prompts load S
         # tokens per prefill tick through the (B, S) chunk step, leaving
         # the final prompt token for the decode step (bit-exact first
-        # sample).  0 = token-by-token reference mode.  Non-uniform
-        # families (SSM recurrences, sliding-window ring buffers) cannot
-        # address the cache positionally and silently fall back.
+        # sample).  0 = token-by-token reference mode.  The xLSTM and
+        # zamba2 recurrences and sliding-window ring buffers cannot
+        # address the cache positionally and silently fall back; the
+        # layered family's Mamba2 layers chunk from each slot's state.
         topo = M.topology(cfg)
-        self.chunkable = topo.kind == "uniform" and not cfg.sliding_window
+        self.chunkable = topo.kind in ("uniform", "layered") \
+            and not cfg.sliding_window
         self.prefill_chunk = int(prefill_chunk) if self.chunkable else 0
         assert self.prefill_chunk >= 0, prefill_chunk
         # admission: "cost" (default) admits the cheapest queued request
@@ -371,8 +376,8 @@ class DecodeServer:
         self.n_pages = 0
         if self.page_size:
             assert self.chunkable, (
-                "paged KV caches need the uniform dense-attention family "
-                f"(got family={cfg.family!r}, "
+                "paged KV caches need the uniform or layered family with "
+                f"full attention (got family={cfg.family!r}, "
                 f"sliding_window={cfg.sliding_window})")
             assert max_len % self.page_size == 0, (
                 f"kv_page_size={self.page_size} must divide "
@@ -466,7 +471,11 @@ class DecodeServer:
         self.key = jax.random.PRNGKey(seed)
         self.greedy = greedy
         self.ticks = 0
-        self._fresh = None  # lazily-built pristine cache for slot resets
+        # one compiled reset for every slot (the slot is traced), updating
+        # the donated cache in place: a layered model's recurrent state is
+        # a large share of the device's memory
+        self._reset = jax.jit(functools.partial(M.reset_slot, self.cfg),
+                              donate_argnums=(0,))
         self._phase_flip = False  # alternates prefill/decode when both ready
         self._submit_seq = 0      # FIFO tiebreak for cost admission
 
@@ -742,15 +751,7 @@ class DecodeServer:
                     self._reserved[i] = need
                     self._reserved_total += need
                 with _span(scopes.RESET_SLOT):
-                    if self._fresh is None:
-                        self._fresh = M.init_cache(self.cfg, self.batch,
-                                                   self.max_len,
-                                                   page_size=self.page_size,
-                                                   kv_pages=self.n_pages)
-                        if self.mesh is not None:
-                            self._fresh = self._shard_cache(self._fresh)
-                    self.cache = M.reset_slot(self.cfg, self.cache,
-                                              self._fresh, i)
+                    self.cache = self._reset(self.cache, i)
                 break
 
     def _abort_unservable(self):

@@ -24,8 +24,15 @@ ATTENTION = "attention"        # q/k/v, rope, scores, softmax, wo (the KV
 MCMA_PLAN = "mcma_plan"        # tick router, route, class sort, capacities
 EXACT_FFN = "exact_ffn"        # the exact leg of a dispatch
 UNEMBED = "unembed"
+SSM_MIXER = "ssm_mixer"        # a Mamba2 mixer: projections, conv, SSD,
+                               # gated norm (SSM_STATE nests inside it)
+SSM_STATE = "ssm_state"        # what reads or writes the mixer's state:
+                               # the conv-state shift, the recurrent state
+                               # update and its read-out C.h, and the
+                               # layer's state out of and back into the
+                               # decode cache's stack
 DEVICE_SCOPES = (KV_WRITE, KV_GATHER, ATTENTION, MCMA_PLAN, EXACT_FFN,
-                 UNEMBED)
+                 UNEMBED, SSM_MIXER, SSM_STATE)
 
 # the weight-switch kernels' pallas_call names
 SWITCH_KERNEL = "mcma_switched_mlp"

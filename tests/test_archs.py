@@ -96,3 +96,18 @@ def test_long500k_skips_match_design():
     ran = {a for a in ARCH_IDS
            if any(c.name == "long_500k" for c in cells(a))}
     assert ran == {"xlstm-1.3b", "zamba2-2.7b", "mixtral-8x7b"}
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_reset_slot_restores_the_init_values(arch):
+    """Resetting every slot of a used decode cache gives back what
+    ``init_cache`` makes (the sLSTM stabiliser's -1e30 included); resetting
+    one leaves the others as they were."""
+    cfg = smoke_config(get_config(arch))
+    fresh = M.init_cache(cfg, 3, 16)
+    used = jax.tree.map(lambda a: a * 2 + 1, fresh)
+    one = M.reset_slot(cfg, used, 1)
+    for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(fresh)):
+        assert (np.asarray(a) != np.asarray(b)).any()
+    every = M.reset_slot(cfg, M.reset_slot(cfg, one, 0), 2)
+    jax.tree.map(np.testing.assert_array_equal, every, fresh)

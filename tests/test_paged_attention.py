@@ -66,14 +66,15 @@ def _tables(pos, held, seed=0):
     return jnp.asarray(bt)
 
 
-def _gathered(q, ks, vs, layer, bt, lengths):
+def _gathered(q, ks, vs, layer, bt, lengths, scale=None):
     """The gathered XLA path's arithmetic on one layer of the stack."""
     b, h, hd = q.shape
     kh = ks.shape[3]
     ak = layers._gather_pages(ks[layer], bt)
     av = layers._gather_pages(vs[layer], bt)
     qg = q.reshape(b, kh, h // kh, hd)
-    s = jnp.einsum("bgrd,bkgd->bgrk", qg, ak).astype(jnp.float32) * hd ** -0.5
+    s = jnp.einsum("bgrd,bkgd->bgrk", qg, ak).astype(jnp.float32) \
+        * (hd ** -0.5 if scale is None else scale)
     valid = jnp.arange(ak.shape[1])[None, :] < lengths[:, None]
     s = jnp.where(valid[:, None, None, :], s, -1e30)
     w = jax.nn.softmax(s, axis=-1).astype(av.dtype)
@@ -110,6 +111,35 @@ def test_kernel_matches_gathered_path(rep, dtype, tol):
                                    np.asarray(want, np.float32),
                                    atol=tol, rtol=tol)
         # idle and masked slots: finite zeros
+        assert not np.asarray(got[5:], np.float32).any()
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, F32_TOL),
+                                       (jnp.bfloat16, BF16_TOL)],
+                         ids=["f32", "bf16"])
+def test_packed_heads_of_64_match_the_gathered_path(dtype, tol):
+    """granite-4.0-h's attention: 32 query heads and 8 KV heads of 64, the
+    KV heads packed two to a page row of 128 lanes (``layers.pool_rows``;
+    a TPU page copy moves whole rows of 128), at its softmax scale 1/64
+    rather than 1/sqrt(64)."""
+    kh, hd, h = 8, 64, 32
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(6), 3)
+    shape = (L, N, P, kh, hd)
+    ks = jax.random.normal(k1, shape, jnp.float32).astype(dtype)
+    vs = jax.random.normal(k2, shape, jnp.float32).astype(dtype)
+    q = jax.random.normal(k3, (len(POS), h, hd), jnp.float32).astype(dtype)
+    bt = _tables(POS, HELD)
+    lengths = jnp.where(jnp.asarray(MASK),
+                        held_lengths(jnp.asarray(POS), bt, P), 0)
+    rows = lambda a: a.reshape(L, N, P, kh * hd // 128, 128)
+    for layer in range(L):
+        got = paged_attention(q, rows(ks), rows(vs), jnp.int32(layer), bt,
+                              lengths, scale=1 / 64, interpret=True)
+        want = _gathered(q, ks, vs, layer, bt, lengths, scale=1 / 64)
+        assert got.dtype == dtype and got.shape == q.shape
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   atol=tol, rtol=tol)
         assert not np.asarray(got[5:], np.float32).any()
 
 

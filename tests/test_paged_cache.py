@@ -106,12 +106,11 @@ def test_init_cache_paged_layout():
 def test_reset_slot_clears_block_table_row_only():
     cfg = _cfg()
     c = M.init_cache(cfg, 3, 32, page_size=8, kv_pages=6)
-    fresh = M.init_cache(cfg, 3, 32, page_size=8, kv_pages=6)
     c = dict(c)
     c["block_table"] = jnp.asarray(
         [[0, 1, -1, -1], [2, 3, 4, -1], [5, -1, -1, -1]], jnp.int32)
     c["k"] = c["k"] + 1.0                       # pool contents are SHARED
-    c2 = M.reset_slot(cfg, c, fresh, 1)
+    c2 = M.reset_slot(cfg, c, 1)
     bt = np.asarray(c2["block_table"])
     assert (bt[1] == -1).all()                  # the reset slot's row
     assert (bt[0] == [0, 1, -1, -1]).all()      # neighbours untouched

@@ -55,15 +55,50 @@ def test_scope_and_kernel_names_are_in_the_lowered_step(step, backend,
     paths = _op_paths(_server(backend), step)
     # a chunk step samples nothing, so it has no unembed; a decode step
     # under a Pallas backend reads its pages in place, so it gathers
-    # nothing and runs the paged attention kernel instead
+    # nothing and runs the paged attention kernel instead; a dense model
+    # has no Mamba2 mixer
     absent = {"chunk": (scopes.UNEMBED,),
-              "decode": (scopes.KV_GATHER,)}[step]
+              "decode": (scopes.KV_GATHER,)}[step] \
+        + (scopes.SSM_MIXER, scopes.SSM_STATE)
     for name in scopes.DEVICE_SCOPES:
         assert any(f"/{name}/" in p for p in paths) != (name in absent), \
             name
     assert any(f"/{kernel}/" in p for p in paths)
     assert any(f"/{scopes.PAGED_ATTENTION}/" in p for p in paths) \
         == (step == "decode")
+
+
+def _layered_server() -> DecodeServer:
+    from repro.configs.base import ApproxConfig, ModelConfig, SSMConfig
+    cfg = ModelConfig(
+        name="layered", family="layered", n_layers=3, d_model=64,
+        n_heads=4, n_kv_heads=2, head_dim=64, d_ff=128, vocab=512,
+        rope_pct=0.0, tie_embeddings=True,
+        layer_types=("mamba", "mamba", "attention"),
+        ssm=SSMConfig(d_state=16, expand=1, head_dim=32, chunk=8,
+                      conv_width=4, conv_bias=True),
+        approx=ApproxConfig(enable=True, n_approx=2, d_hidden=32,
+                            interpret=True),
+        param_dtype="float32", act_dtype="float32", remat=False)
+    params = M.init_model(jax.random.PRNGKey(0), cfg)
+    return DecodeServer(cfg, params, options=ServeOptions(
+        batch=BATCH, max_len=MAX_LEN, use_mcma_dispatch=True,
+        route_scope="tick", kv_page_size=PAGE, prefill_chunk=CHUNK))
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_the_state_scope_nests_inside_the_mixer(step):
+    """A layered model's Mamba2 work is under ``ssm_mixer``, and what
+    reads or writes its state under ``ssm_mixer/ssm_state``, once each."""
+    paths = _op_paths(_layered_server(), step)
+    mixer, state = scopes.SSM_MIXER, scopes.SSM_STATE
+    assert any(f"/{mixer}/" in p and f"/{state}/" not in p for p in paths)
+    assert any(f"/{mixer}/{state}/" in p for p in paths)
+    for p in paths:
+        parts = p.split("/")
+        assert parts.count(mixer) <= 1 and parts.count(state) <= 1, p
+        if state in parts:
+            assert mixer in parts[:parts.index(state)], p
 
 
 def test_the_kv_scopes_nest_inside_attention():

@@ -155,7 +155,8 @@ _MOVES = ("copy", "copy-start", "copy-done", "dynamic-slice",
           "dynamic-update-slice", "slice", "concatenate", "custom-call")
 
 
-def _check_pages_read_in_place(hlo: str, n_layers: int, kh: int, hd: int):
+def _check_pages_read_in_place(hlo: str, n_layers: int, kh: int, hd: int,
+                               n_pages: int = N_PAGES):
     """The decode step runs the paged attention kernel, and nothing in it
     (fused computations included) produces a layer's ``(N, P, Kh, hd)``
     pool or copies or slices the ``(L, N, P, Kh, hd)`` stack: the step's
@@ -164,9 +165,80 @@ def _check_pages_read_in_place(hlo: str, n_layers: int, kh: int, hd: int):
               if re.search(rf"%{scopes.PAGED_ATTENTION}[.\d]* = ", ln)]
     assert kernel and all('custom_call_target="tpu_custom_call"' in ln
                           for ln in kernel), kernel
-    stack = f"{n_layers},{N_PAGES},{PAGE},{kh},{hd}"
-    pool = f"{N_PAGES},{PAGE},{kh},{hd}"
+    stack = f"{n_layers},{n_pages},{PAGE},{kh},{hd}"
+    pool = f"{n_pages},{PAGE},{kh},{hd}"
     for m in re.finditer(r"= \(?\w+\[([\d,]+)\]\S*\)? ([\w-]+)\(", hlo):
         shape, op = m.groups()
         assert shape != pool, m.group(0)
         assert not (shape == stack and op in _MOVES), m.group(0)
+
+
+GRANITE_BATCH = 32
+GRANITE_PAGES = GRANITE_BATCH * MAX_LEN // PAGE
+N_PP = MAX_LEN // PAGE
+
+
+def _granite():
+    """granite-4.0-h-micro as the benchmark's configuration file has it
+    (40 layers, published widths), with the compiled kernels."""
+    from chipbench import run
+    cfg = run.model_config(run.load_config("granite-4.0-h-micro"))
+    return dataclasses.replace(cfg, approx=dataclasses.replace(
+        cfg.approx, backend="pallas", interpret=False, route_scope="tick"))
+
+
+def test_packed_paged_kernel_compiles_for_v5e(one_chip, no_persistent_cache):
+    """granite-4.0-h's attention layers: 32 query and 8 KV heads of 64,
+    two KV heads to a page row of 128 lanes (``layers.pool_rows``); a
+    head of 64 in a row of its own is refused by the TPU compiler."""
+    from repro.kernels.paged_attention import paged_attention
+    from repro.models.layers import pool_rows
+    rows, width = pool_rows(_granite())
+    assert (rows, width) == (4, 128)
+    bf = jnp.bfloat16
+    fn = jax.jit(lambda q, k, v, layer, bt, n: paged_attention(
+        q, k, v, layer, bt, n, scale=1 / 64))
+    stack = _spec(one_chip, (2, N_PAGES, PAGE, rows, width), bf)
+    compiled = fn.lower(
+        _spec(one_chip, (BATCH, 32, 64), bf), stack, stack,
+        _spec(one_chip, (), jnp.int32),
+        _spec(one_chip, (BATCH, N_PP), jnp.int32),
+        _spec(one_chip, (BATCH,), jnp.int32)).compile()
+    _check(compiled)
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill_chunk"])
+def test_layered_served_step_compiles_for_v5e(one_chip, no_persistent_cache,
+                                              step):
+    """The served tick-scope step of granite-4.0-h-micro as the chat cell
+    runs it: full width and depth, batch 32, pages of 16, chunks of 64
+    (the periods of its layer pattern are scanned, so the compile barely
+    depends on depth).  The decode step reads its attention layers'
+    pages in place and updates the Mamba2 state stack without copying
+    it, and the whole fits the chip's 16 GB."""
+    from repro.models.layers import pool_rows
+    cfg = _granite()
+    batch, chunk, n_pages = GRANITE_BATCH, 64, GRANITE_PAGES
+    place = lambda tree: jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: M.init_model(jax.random.PRNGKey(0), cfg)))
+    cache = place(jax.eval_shape(lambda: M.init_cache(
+        cfg, batch, MAX_LEN, page_size=PAGE, kv_pages=n_pages)))
+    if step == "decode":
+        fn = steps.make_decode_step(cfg, with_stats=True)
+        args = (_spec(one_chip, (batch, 1), jnp.int32),
+                _spec(one_chip, (batch,), jnp.bool_))
+    else:
+        fn = steps.make_prefill_chunk_step(cfg, with_stats=True)
+        args = (_spec(one_chip, (batch, chunk), jnp.int32),
+                _spec(one_chip, (batch,), jnp.int32))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    _check(compiled)
+    if step == "decode":
+        hlo = compiled.as_text()
+        rows, width = pool_rows(cfg)
+        _check_pages_read_in_place(hlo, 4, rows, width, n_pages)
+        state = f"f32[36,{batch},64,64,128]"
+        assert not re.search(rf"= {re.escape(state)}\S* copy\(", hlo)
